@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import importlib
+import os
 import sys
 
 BENCHES = [
@@ -50,6 +51,9 @@ def main() -> None:
     ap.add_argument("--only", type=str, default=None)
     args = ap.parse_args()
     names = [args.only] if args.only else BENCHES
+    # the mesh benches (fmi_vs_xla, kmeans) run in this process; on the CPU
+    # they need host devices, which must be requested before JAX starts
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

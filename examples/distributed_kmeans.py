@@ -63,13 +63,12 @@ def kmeans_mesh(epochs: int = 5, P: int = 8, n_local: int = 2048, d: int = 28,
     os.environ.setdefault("XLA_FLAGS", f"--xla_force_host_platform_device_count={P}")
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as Pspec
+    from jax.sharding import AxisType, PartitionSpec as Pspec
 
-    from repro import compat
     from repro.core import collectives as C
     from repro.core.communicator import Communicator
 
-    mesh = compat.make_mesh((P,), ("data",), auto_axes=True)
+    mesh = jax.make_mesh((P,), ("data",), axis_types=(AxisType.Auto,))
     comm = Communicator(axes=("data",), sizes=(P,))
     rng = np.random.default_rng(0)
     pts = jnp.asarray(rng.normal(size=(P * n_local, d)), jnp.float32)
@@ -86,11 +85,11 @@ def kmeans_mesh(epochs: int = 5, P: int = 8, n_local: int = 2048, d: int = 28,
         stats = C.allreduce(stats, comm, algorithm="auto")
         return stats[:, :-1] / jnp.maximum(stats[:, -1:], 1.0)
 
-    step = jax.jit(compat.shard_map(
+    step = jax.jit(jax.shard_map(
         epoch, mesh=mesh, in_specs=(Pspec("data", None), Pspec(None, None)),
         out_specs=Pspec(None, None), axis_names={"data"}, check_vma=False,
     ))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for e in range(epochs):
             cents = step(pts, cents)
             inertia = float(jnp.sum(jnp.min(jnp.sum(

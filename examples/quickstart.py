@@ -16,16 +16,15 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro import compat
 from repro.core import collectives as C
 from repro.core.communicator import Communicator
 from repro.core.selector import explain, select
 
 
 def main():
-    mesh = compat.make_mesh((8,), ("world",), auto_axes=True)
+    mesh = jax.make_mesh((8,), ("world",), axis_types=(AxisType.Auto,))
     # "Here, the communicator contains 8 functions; each has a unique id"
     comm = Communicator(axes=("world",), sizes=(8,), name="world")
 
@@ -44,14 +43,14 @@ def main():
         ranks = C.scan(jnp.ones((1,)), comm)
         return chunk, biggest, ranks, me
 
-    run = jax.jit(compat.shard_map(
+    run = jax.jit(jax.shard_map(
         lambda v: tuple(o[None] for o in program(v[0])),
         mesh=mesh, in_specs=P("world", None),
         out_specs=(P("world", None), P("world", None), P("world", None), P("world")),
         axis_names={"world"},
     ))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 16)), jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         chunk, biggest, ranks, me = run(x)
 
     for r in range(8):

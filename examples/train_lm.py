@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
+from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.launch.mesh import make_host_mesh
@@ -59,7 +59,7 @@ def main():
     dcfg = DataConfig()
     ckpt = CheckpointManager(args.ckpt_dir)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(cfg, jax.random.key(0))
         opt = init_opt_state(cfg, tcfg, params)
         losses, t0 = [], time.perf_counter()

@@ -143,21 +143,28 @@ def allreduce(x, comm: Communicator, op="add", algorithm="auto", objective="time
 
 
 def reduce_scatter(x, comm: Communicator, op="add", algorithm="auto",
-                   pipeline: int | None = None):
+                   pipeline: int | None = None, rows: bool = False):
     """Returns this rank's reduced chunk of ``x`` raveled: shape
-    ``[ceil(x.size/P)]`` under the natural convention (rank r owns chunk r)."""
+    ``[ceil(x.size/P)]`` under the natural convention (rank r owns chunk r).
+
+    ``rows=True`` splits ``x`` along its leading axis instead, with no
+    ravel: rank r gets row block r, ``[x.shape[0] // P, ...]``, and
+    ``x.shape[0]`` must be a multiple of P (mesh transports only).  On the
+    TPU a ravel of a matrix is a relayout copy, and at gradient size the
+    compiler takes minutes over it."""
     _observe("reduce_scatter", x, comm)
     if comm.size == 1:
-        return x.reshape(-1)
+        return x if rows else x.reshape(-1)
     t = comm.transport()
     algorithm, depth = _resolve("reduce_scatter", x, comm, algorithm, "time", t)
     if pipeline is not None:
         depth = int(pipeline)
-    flat, n = _pad_flat(x, comm.size, t)
+    flat = _row_blocks(x, comm.size, t) if rows else _pad_flat(x, comm.size, t)[0]
     if algorithm == "xla":
         if op != "add":
             raise ValueError("xla reduce_scatter supports add")
-        return jax.lax.psum_scatter(flat, comm.axis_arg, scatter_dimension=0, tiled=True)
+        return jax.lax.psum_scatter(x if rows else flat, comm.axis_arg,
+                                    scatter_dimension=0, tiled=True)
     if algorithm == "recursive_halving":
         if depth > 1:
             return A.halving_reduce_scatter_pipelined(t, flat, op, depth=depth)
@@ -174,24 +181,42 @@ def reduce_scatter(x, comm: Communicator, op="add", algorithm="auto",
     raise ValueError(f"unknown reduce_scatter algorithm {algorithm!r}")
 
 
-def allgather(chunk, comm: Communicator, algorithm="auto"):
+def _row_blocks(x, P: int, t):
+    """``x [n, ...]`` viewed as ``[P, n // P, ...]``: the row blocks of
+    ``rows=True`` collectives."""
+    if t.stacked or x.ndim < 2 or x.shape[0] % P:
+        raise ValueError(f"rows=True needs a mesh transport, a 2-D or wider x "
+                         f"and a leading axis divisible by {P}; got shape "
+                         f"{tuple(x.shape)}")
+    return x.reshape((P, x.shape[0] // P) + tuple(x.shape[1:]))
+
+
+def allgather(chunk, comm: Communicator, algorithm="auto", rows: bool = False):
     """Natural convention: rank r contributes chunk r; returns flat
     ``[P * chunk.size]`` (leading concat over ranks; on stacked software
-    transports the result is ``[P, P * chunk.size]``)."""
+    transports the result is ``[P, P * chunk.size]``).  ``rows=True`` is
+    the inverse of ``reduce_scatter(rows=True)``: the row blocks stacked,
+    ``[P * chunk.shape[0], ...]``, with no ravel."""
     _observe("allgather", chunk, comm)
     if comm.size == 1:
-        return chunk.reshape(-1)
+        return chunk if rows else chunk.reshape(-1)
     if algorithm == "auto":
         # doubling is pow2-only; ring handles any rank count
         algorithm = "recursive_doubling" if _is_pow2(comm.size) else "ring"
     if algorithm == "xla":
-        return jax.lax.all_gather(chunk.reshape(-1), comm.axis_arg, tiled=True)
+        return jax.lax.all_gather(chunk if rows else chunk.reshape(-1),
+                                  comm.axis_arg, tiled=True)
     t = comm.transport()
     fn = (
         A.doubling_allgather
         if algorithm == "recursive_doubling"
         else A.allgather_natural_ring
     )
+    if rows:
+        if t.stacked or chunk.ndim < 2:
+            raise ValueError(f"rows=True needs a mesh transport and a 2-D or "
+                             f"wider chunk; got shape {tuple(chunk.shape)}")
+        return fn(t, chunk).reshape((-1,) + tuple(chunk.shape[1:]))
     if t.stacked:
         out = fn(t, t.xp.reshape(t.xp.asarray(chunk), (t.size, -1)))
         return t.xp.reshape(out, (t.size, -1))

@@ -302,23 +302,25 @@ def iallreduce(x, comm, op="add", algorithm="auto", objective="time",
 
 def ireduce_scatter(x, comm, op="add", algorithm="auto",
                     pipeline: int | None = None,
-                    finalize: Callable[[Any], Any] | None = None) -> Request:
+                    finalize: Callable[[Any], Any] | None = None,
+                    rows: bool = False) -> Request:
     """Nonblocking reduce-scatter → Request for this rank's reduced chunk."""
     from . import collectives as C
 
     return _issue("reduce_scatter", _payload_bytes(x),
                   lambda: C.reduce_scatter(x, comm, op=op, algorithm=algorithm,
-                                           pipeline=pipeline),
+                                           pipeline=pipeline, rows=rows),
                   finalize=finalize, comm=comm)
 
 
 def iallgather(chunk, comm, algorithm="auto",
-               finalize: Callable[[Any], Any] | None = None) -> Request:
+               finalize: Callable[[Any], Any] | None = None,
+               rows: bool = False) -> Request:
     """Nonblocking allgather → Request for the full concatenated buffer."""
     from . import collectives as C
 
     return _issue("allgather", _payload_bytes(chunk),
-                  lambda: C.allgather(chunk, comm, algorithm=algorithm),
+                  lambda: C.allgather(chunk, comm, algorithm=algorithm, rows=rows),
                   finalize=finalize, comm=comm)
 
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 NEG_INF = -1e30
 
@@ -125,10 +124,12 @@ def flash_attention(
     q_offset: int = 0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
-    """GQA flash attention.  ``interpret=True`` executes the kernel body on
-    CPU for validation; on TPU pass ``interpret=False``."""
+    """GQA flash attention forward.  Compiled for the TPU by default;
+    ``interpret=True`` executes the kernel body on the CPU for validation.
+    Differentiate through :func:`repro.kernels.ops.flash_attention`, which
+    gives this kernel its backward pass."""
     B, Hq, T, d = q.shape
     _, Hkv, S, dv = v.shape
     if Hq % Hkv:
@@ -171,7 +172,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -10,7 +10,13 @@ Three backends per op:
   the dry-run compiles, so HLO cost/memory analysis reflects a flash-style
   schedule, not an O(T²)-memory naive attention.
 
-``backend='auto'`` picks pallas on TPU and xla elsewhere.
+``backend='auto'`` picks pallas on TPU and xla elsewhere; it never picks
+the interpreter or the reference.  ``flash_attention`` has one more rule:
+a traced ``q_offset`` runs the xla backend whatever was asked (see
+:func:`flash_backend`).
+
+A Mosaic kernel cannot be partitioned by GSPMD, so under a mesh the
+callers map it over shards with :func:`per_shard`.
 """
 
 from __future__ import annotations
@@ -19,6 +25,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import flash_attention as _fa
 from . import quantize as _qz
@@ -30,6 +37,26 @@ NEG_INF = -1e30
 
 def _default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def per_shard(fn, spec: P):
+    """``fn`` mapped over the shards of the ambient mesh, every operand and
+    the result laid out as ``spec``; ``None`` where no such map can hold a
+    Mosaic kernel.
+
+    GSPMD refuses to partition a Mosaic kernel, and refuses one inside a
+    ``shard_map`` that leaves any mesh axis automatic, even of size 1.  So:
+    outside a mesh, or where every axis is already manual, ``fn`` itself;
+    under a mesh with no manual axis, ``fn`` in a ``shard_map`` over all of
+    them; inside a ``shard_map`` that leaves some axis automatic, ``None``
+    (nested maps do not merge their manual axes)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or set(mesh.manual_axes) == set(mesh.axis_names):
+        return fn
+    if mesh.manual_axes:
+        return None
+    return jax.shard_map(fn, in_specs=spec, out_specs=spec,
+                         axis_names=set(mesh.axis_names), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -101,29 +128,69 @@ def _xla_flash_attention(q, k, v, causal=True, window=0, q_offset=0, bk=512):
     return out.astype(q.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "window", "backend", "bq", "bk")
-)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _kernel_flash(q, k, v, causal, window, q_offset, bq, bk, interpret):
+    return _fa.flash_attention(
+        q, k, v, causal=causal, window=window, q_offset=q_offset,
+        bq=bq, bk=bk, interpret=interpret,
+    )
+
+
+def _kernel_flash_fwd(q, k, v, causal, window, q_offset, bq, bk, interpret):
+    out = _kernel_flash(q, k, v, causal, window, q_offset, bq, bk, interpret)
+    return out, (q, k, v)
+
+
+def _kernel_flash_bwd(causal, window, q_offset, bq, bk, interpret, res, g):
+    # The backward pass is the VJP of the chunked XLA twin: it computes the
+    # same function as the kernel with the same f32 online-softmax numerics,
+    # and its per-block checkpoint recomputes the score tiles instead of
+    # saving them, which is flash attention's backward.  It walks the
+    # kernel's kv block size, which bounds its [T, bk] f32 tiles.
+    q, k, v = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: _xla_flash_attention(q, k, v, causal, window, q_offset,
+                                             bk=bk),
+        q, k, v,
+    )
+    return vjp(g)
+
+
+_kernel_flash.defvjp(_kernel_flash_fwd, _kernel_flash_bwd)
+
+
+def flash_backend(backend: str = "auto", q_offset=0) -> str:
+    """The backend :func:`flash_attention` runs for these arguments.
+
+    ``auto`` is ``pallas`` on the TPU and ``xla`` elsewhere.  A traced
+    ``q_offset`` (a decode position inside ``jit``) always runs ``xla``,
+    whatever was asked: the kernel bakes its offset into the grid, so it
+    needs a static one.  Training and prefill pass ``q_offset=0``."""
+    if backend == "auto":
+        backend = _default_backend()
+    return backend if isinstance(q_offset, int) else "xla"
+
+
+_xla_flash = jax.jit(_xla_flash_attention, static_argnums=(3, 4))
+
+
 def flash_attention(
     q, k, v, causal=True, window=0, q_offset=0, backend="auto", bq=128, bk=128
 ):
     """GQA flash attention: q [B,Hq,T,d], k/v [B,Hkv,S,d(v)] -> [B,Hq,T,dv].
 
-    ``q_offset`` may be dynamic (a traced position — the decode path); the
-    Pallas kernel needs it static, so dynamic offsets fall back to the xla
-    backend (decode is a matvec anyway — the kernel targets train/prefill).
+    The backend is :func:`flash_backend` ``(backend, q_offset)``: a traced
+    ``q_offset`` runs the xla twin.  The dispatch runs in Python, before
+    any ``jit``, so a Python-int offset stays static.  The kernel backends
+    are differentiable; their backward pass is the xla twin's.
     """
-    if backend == "auto":
-        backend = _default_backend()
-    static_off = isinstance(q_offset, int)
-    if backend == "xla" or not static_off:
-        return _xla_flash_attention(q, k, v, causal, window, q_offset)
+    backend = flash_backend(backend, q_offset)
+    if backend == "xla":
+        return _xla_flash(q, k, v, causal, window, q_offset)
     if backend == "ref":
         return _ref.attention(q, k, v, causal, window, q_offset)
-    return _fa.flash_attention(
-        q, k, v, causal=causal, window=window, q_offset=q_offset,
-        bq=bq, bk=bk, interpret=(backend == "interpret"),
-    )
+    return _kernel_flash(q, k, v, causal, window, q_offset, bq, bk,
+                         backend == "interpret")
 
 
 # ---------------------------------------------------------------------------

@@ -45,7 +45,8 @@ Worked example — 3 tokens spread over 2 non-contiguous pages of 2 slots::
     >>> kp = jnp.ones((2, 2, 1, 4), jnp.float32)        # [pages, slots, Hkv, d]
     >>> vp = jnp.asarray(np.arange(16., dtype=np.float32).reshape(2, 2, 1, 4))
     >>> table = jnp.asarray([[1, 0]], jnp.int32)        # page order: 1 then 0
-    >>> out = paged_attention(q, kp, vp, table, jnp.asarray([3], jnp.int32))
+    >>> out = paged_attention(q, kp, vp, table, jnp.asarray([3], jnp.int32),
+    ...                       interpret=True)          # CPU: the interpreter
     >>> out.shape                                       # [B, Hq, dv]
     (1, 2, 4)
     >>> bool(np.allclose(out[0, 0],                     # uniform over 3 slots
@@ -62,7 +63,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 NEG_INF = -1e30
 
@@ -70,23 +70,27 @@ NEG_INF = -1e30
 def _paged_kernel(
     table_ref,  # scalar prefetch [B, npm] i32
     lengths_ref,  # scalar prefetch [B] i32
-    kvh_ref,  # scalar prefetch [Hq] i32 (unused in body; drives index maps)
-    off_ref,  # scalar prefetch [Hq] i32 (unused in body; drives index maps)
-    q_ref,  # [1, 1, d]
-    k_ref,  # [1, ps, 1, d]
-    v_ref,  # [1, ps, 1, dv]
-    ks_ref,  # [1, 1] f32 per-(page, kv head) K scale
-    vs_ref,  # [1, 1] f32 per-(page, kv head) V scale
-    o_ref,  # [1, 1, dv]
-    m_ref,  # scratch [1] f32
-    l_ref,  # scratch [1] f32
+    kvh_ref,  # scalar prefetch [Hq] i32
+    off_ref,  # scalar prefetch [Hq] i32
+    q_ref,  # [1, 1, 1, d]
+    k_ref,  # [1, ps, Hkv, d]  the whole page: every kv head
+    v_ref,  # [1, ps, Hkv, dv]
+    ks_ref,  # [scale_rows, Hkv] f32 K-scale rows around this page
+    vs_ref,  # [scale_rows, Hkv] f32 V-scale rows around this page
+    o_ref,  # [1, 1, 1, dv]
+    m_ref,  # scratch [1, 1] f32
+    l_ref,  # scratch [1, 1] f32
     acc_ref,  # scratch [1, dv] f32
     *,
     page_size: int,
     sm_scale: float,
+    scale_rows: int,
 ):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     p = pl.program_id(2)
+    kvh = kvh_ref[h]
+    page = table_ref[b, p] + off_ref[h]
 
     @pl.when(p == 0)
     def _init():
@@ -94,36 +98,49 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [d]
-    k = k_ref[0, :, 0].astype(jnp.float32)  # [ps, d]
-    v = v_ref[0, :, 0].astype(jnp.float32)  # [ps, dv]
+    # Mosaic cannot index a packed (int8/bf16) block at a dynamic sublane,
+    # so kv head ``kvh`` is picked by a masked sum over the head axis: the
+    # other heads add exact +0.0, the selected rows keep their bits
+    def head_rows(ref):  # -> [ps, d] f32
+        x = ref[0].astype(jnp.float32)  # [ps, Hkv, d]
+        sel = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) == kvh
+        return jnp.sum(jnp.where(sel, x, 0.0), axis=1)
 
-    slot = page_size * p + jax.lax.broadcasted_iota(jnp.int32, (page_size,), 0)
+    def head_scale(ref):  # -> [1, 1]
+        row = ref[pl.ds(page % scale_rows, 1), :]  # [1, Hkv]
+        sel = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) == kvh
+        return jnp.sum(jnp.where(sel, row, 0.0), axis=1, keepdims=True)
+
+    q = q_ref[0, 0].astype(jnp.float32)  # [1, d]
+    k = head_rows(k_ref)  # [ps, d]
+    v = head_rows(v_ref)  # [ps, dv]
+
+    slot = page_size * p + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     visible = slot < lengths_ref[b]
 
     # quantized pages: scores/values carry the per-(page, head) scales as
     # scalar multiplies (for f32/bf16 pools the scales are exactly 1.0, and
     # x * 1.0 is the identity in IEEE arithmetic — one code path, same bits)
     s = jax.lax.dot_general(
-        k, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * (ks_ref[0, 0] * sm_scale)  # [ps]
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * (head_scale(ks_ref) * sm_scale)  # [1, ps]
     s = jnp.where(visible, s, NEG_INF)
 
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     pr = jnp.exp(s - m_new)
     pr = jnp.where(visible, pr, 0.0)  # padded tails: exact +0.0
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(pr)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pr, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )[None] * vs_ref[0, 0]
-    m_ref[0] = m_new
+        pr, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    ) * head_scale(vs_ref)
+    m_ref[...] = m_new
 
     @pl.when(p == pl.num_programs(2) - 1)
     def _finalize():
         # an all-masked row (length 0: a batch-padding row) yields exact 0.0
-        o_ref[0, 0] = (acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)).astype(
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -139,11 +156,11 @@ def paged_attention(
     kv_head: jax.Array | None = None,  # [Hq] i32 (None = GQA h // group)
     page_offset: jax.Array | None = None,  # [Hq] i32 (None = zeros)
     sm_scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Decode attention straight off the paged pool -> ``[B, Hq, dv]`` f32
-    math (returned in ``q.dtype``).  ``interpret=True`` executes the kernel
-    body on CPU for validation; on TPU pass ``interpret=False``."""
+    math (returned in ``q.dtype``).  Compiled for the TPU by default;
+    ``interpret=True`` executes the kernel body on the CPU for validation."""
     B, Hq, d = q.shape
     n_pages, ps, Hkv, dv = v_pages.shape
     npm = table.shape[1]
@@ -160,38 +177,49 @@ def paged_attention(
     if page_offset is None:
         page_offset = jnp.zeros((Hq,), jnp.int32)
 
+    # TPU blocks must be (8, 128)-aligned in their last two dimensions or
+    # span them: q/out travel as [B, Hq, 1, d] rows, K/V as whole pages
+    # (every kv head), and the scales as the aligned group of up to eight
+    # pages that holds this one
+    scale_rows = min(8, n_pages)
+
     def kv_map(bb, h, p, tbl, ln, kvh, off):
-        return (tbl[bb, p] + off[h], 0, kvh[h], 0)
+        return (tbl[bb, p] + off[h], 0, 0, 0)
 
     def sc_map(bb, h, p, tbl, ln, kvh, off):
-        return (tbl[bb, p] + off[h], kvh[h])
+        return ((tbl[bb, p] + off[h]) // scale_rows, 0)
+
+    def row_map(bb, h, p, *_):
+        return (bb, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, Hq, npm),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bb, h, p, *_: (bb, h, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, dv), kv_map),
-            pl.BlockSpec((1, 1), sc_map),
-            pl.BlockSpec((1, 1), sc_map),
+            pl.BlockSpec((1, 1, 1, d), row_map),
+            pl.BlockSpec((1, ps, Hkv, d), kv_map),
+            pl.BlockSpec((1, ps, Hkv, dv), kv_map),
+            pl.BlockSpec((scale_rows, Hkv), sc_map),
+            pl.BlockSpec((scale_rows, Hkv), sc_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv), lambda bb, h, p, *_: (bb, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, dv), row_map),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_kernel, page_size=ps, sm_scale=sm_scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_paged_kernel, page_size=ps, sm_scale=sm_scale,
+                               scale_rows=scale_rows)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, dv), q.dtype),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
       kv_head.astype(jnp.int32), page_offset.astype(jnp.int32),
-      q, k_pages, v_pages, k_scale, v_scale)
+      q.reshape(B, Hq, 1, d), k_pages, v_pages, k_scale, v_scale)
+    return out.reshape(B, Hq, dv)

@@ -11,10 +11,11 @@ pools — the granularity ``kernels/paged_attention.py`` dequantizes at (a
 scalar multiply per page block) and ``serving/kv_cache.py`` stores
 alongside the pool under ``kv_dtype='int8'``.
 
-Tiling: rows × lane-tiles; each grid step owns a [tr, tn] VMEM tile where
-``tn`` is a multiple of the quantization block (and of the 128-lane VPU
-width for the TPU target), so the max-abs reduction is a purely local
-reshape-reduce with no cross-tile traffic.
+Tiling.  TPU blocks must be (8, 128)-aligned in their last two dimensions
+or span the whole array there.  The blockwise kernels take ``[8, 128·block]``
+tiles, so the scale tile is ``[8, 128]``; the page kernels take eight whole
+pages ``[8, page_size, H, d]`` per step, so the scale tile is ``[8, H]``.
+Every max-abs reduction stays local to its tile.
 
 Validated against repro.kernels.ref.quantize_blockwise in interpret mode.
 """
@@ -46,20 +47,27 @@ def _dequant_kernel(q_ref, s_ref, o_ref, *, block: int):
     o_ref[...] = (qb * s_ref[...][..., None]).reshape(tr, tn).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "tile_rows", "tile_cols", "interpret"))
+_ROWS = 8  # sublane tile
+_LANES = 128  # lane tile: scales per blockwise tile, one row of lanes
+
+
+def _block_tiles(R: int, N: int, block: int):
+    """(rows, columns, grid) of the blockwise kernels' tiles."""
+    if N % block:
+        raise ValueError(f"N={N} not a multiple of block={block}")
+    tr = min(_ROWS, R)
+    nb = min(_LANES, N // block)
+    return tr, nb * block, (pl.cdiv(R, tr), pl.cdiv(N // block, nb))
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def quantize_blockwise(
     x: jax.Array,  # [R, N], N % block == 0
     block: int = 256,
-    tile_rows: int = 8,
-    tile_cols: int = 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     R, N = x.shape
-    tr = min(tile_rows, R)
-    tn = min(max(block, tile_cols - tile_cols % block), N)
-    if N % block:
-        raise ValueError(f"N={N} not a multiple of block={block}")
-    grid = (pl.cdiv(R, tr), pl.cdiv(N, tn))
+    tr, tn, grid = _block_tiles(R, N, block)
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, block=block),
         grid=grid,
@@ -77,20 +85,16 @@ def quantize_blockwise(
     return q, s
 
 
-@functools.partial(jax.jit, static_argnames=("block", "tile_rows", "tile_cols", "interpret", "out_dtype"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "out_dtype"))
 def dequantize_blockwise(
     q: jax.Array,  # [R, N] int8
     s: jax.Array,  # [R, N/block] f32
     block: int = 256,
-    tile_rows: int = 8,
-    tile_cols: int = 1024,
-    interpret: bool = True,
+    interpret: bool = False,
     out_dtype=jnp.float32,
 ):
     R, N = q.shape
-    tr = min(tile_rows, R)
-    tn = min(max(block, tile_cols - tile_cols % block), N)
-    grid = (pl.cdiv(R, tr), pl.cdiv(N, tn))
+    tr, tn, grid = _block_tiles(R, N, block)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, block=block),
         grid=grid,
@@ -111,32 +115,32 @@ def dequantize_blockwise(
 
 
 def _quant_page_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[0, :, 0].astype(jnp.float32)  # [ps, d]
-    amax = jnp.max(jnp.abs(x))
+    x = x_ref[...].astype(jnp.float32)  # [tp, ps, H, d]
+    amax = jnp.max(jnp.max(jnp.abs(x), axis=1), axis=-1)  # [tp, H]
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q_ref[0, :, 0] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    s_ref[0, 0] = scale.astype(jnp.float32)
+    q = jnp.clip(jnp.round(x / scale[:, None, :, None]), -127, 127)
+    q_ref[...] = q.astype(jnp.int8)
+    s_ref[...] = scale
 
 
 def _dequant_page_kernel(q_ref, s_ref, o_ref):
-    q = q_ref[0, :, 0].astype(jnp.float32)
-    o_ref[0, :, 0] = (q * s_ref[0, 0]).astype(o_ref.dtype)
+    q = q_ref[...].astype(jnp.float32)  # [tp, ps, H, d]
+    o_ref[...] = (q * s_ref[...][:, None, :, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize_page(x: jax.Array, interpret: bool = True):
+def quantize_page(x: jax.Array, interpret: bool = False):
     """KV pages ``[n_pages, page_size, H, d]`` -> (int8 pages, f32 scales
-    ``[n_pages, H]``).  Grid over (page, head): the max-abs reduction is
-    purely block-local."""
+    ``[n_pages, H]``).  Grid over groups of eight pages: the per-(page,
+    head) max-abs reduction is block-local."""
     n_pages, ps, H, d = x.shape
+    tp = min(_ROWS, n_pages)
+    page_block = pl.BlockSpec((tp, ps, H, d), lambda i: (i, 0, 0, 0))
     q, s = pl.pallas_call(
         _quant_page_kernel,
-        grid=(n_pages, H),
-        in_specs=[pl.BlockSpec((1, ps, 1, d), lambda p, h: (p, 0, h, 0))],
-        out_specs=[
-            pl.BlockSpec((1, ps, 1, d), lambda p, h: (p, 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda p, h: (p, h)),
-        ],
+        grid=(pl.cdiv(n_pages, tp),),
+        in_specs=[page_block],
+        out_specs=[page_block, pl.BlockSpec((tp, H), lambda i: (i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((n_pages, ps, H, d), jnp.int8),
             jax.ShapeDtypeStruct((n_pages, H), jnp.float32),
@@ -147,18 +151,17 @@ def quantize_page(x: jax.Array, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
-def dequantize_page(q: jax.Array, s: jax.Array, interpret: bool = True,
+def dequantize_page(q: jax.Array, s: jax.Array, interpret: bool = False,
                     out_dtype=jnp.float32):
     """Inverse of :func:`quantize_page` (per-(page, head) scales)."""
     n_pages, ps, H, d = q.shape
+    tp = min(_ROWS, n_pages)
+    page_block = pl.BlockSpec((tp, ps, H, d), lambda i: (i, 0, 0, 0))
     return pl.pallas_call(
         _dequant_page_kernel,
-        grid=(n_pages, H),
-        in_specs=[
-            pl.BlockSpec((1, ps, 1, d), lambda p, h: (p, 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda p, h: (p, h)),
-        ],
-        out_specs=pl.BlockSpec((1, ps, 1, d), lambda p, h: (p, 0, h, 0)),
+        grid=(pl.cdiv(n_pages, tp),),
+        in_specs=[page_block, pl.BlockSpec((tp, H), lambda i: (i, 0))],
+        out_specs=page_block,
         out_shape=jax.ShapeDtypeStruct((n_pages, ps, H, d), out_dtype),
         interpret=interpret,
     )(q, s)

@@ -34,21 +34,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 
 def _gla_kernel(
     q_ref,  # [1, L, dk]
     k_ref,  # [1, L, dk]
     v_ref,  # [1, L, dv]
-    lf_ref,  # [1, L]  log forget gates
-    ig_ref,  # [1, L]  input gates
+    b_ref,  # [1, 1, L]  chunk-local cumulative log forget gates
+    ig_ref,  # [1, 1, L]  input gates
     o_ref,  # [1, L, dv]
     state_ref,  # out [1, dk, dv+1]  (final state, written at last chunk)
     C_ref,  # scratch [dk, dv+1] f32
     *,
     L: int,
-    dk: int,
     dv: int,
     seq_len: int,
     n_chunks: int,
@@ -64,34 +62,33 @@ def _gla_kernel(
     q = q_ref[0].astype(jnp.float32) * sm_scale  # [L, dk]
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    lf = lf_ref[0].astype(jnp.float32)  # [L]
-    ig = ig_ref[0].astype(jnp.float32)
+    b = b_ref[0]  # [1, L]  log decay from chunk start to (incl.) t
+    ig = ig_ref[0]  # [1, L]  (padded tail: b flat, i = 0 -> identity step)
 
-    # mask padded tail steps: identity transition (f=1 -> log f = 0, i = 0)
-    pos = ci * L + jax.lax.iota(jnp.int32, L)
+    # zero padded tail rows: garbage there would poison the matmuls
+    pos = ci * L + jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     valid = pos < seq_len
-    lf = jnp.where(valid, lf, 0.0)
-    ig = jnp.where(valid, ig, 0.0)
-    v = jnp.where(valid[:, None], v, 0.0)
-    k = jnp.where(valid[:, None], k, 0.0)
+    v = jnp.where(valid, v, 0.0)
+    k = jnp.where(valid, k, 0.0)
 
     v_aug = jnp.concatenate([v, jnp.ones((L, 1), jnp.float32)], axis=-1)  # [L, dv+1]
 
-    b = jnp.cumsum(lf)  # [L]  log decay from chunk start to (incl.) t
+    # gates arrive as lane rows; the column forms come from an [L, L]
+    # transpose (Mosaic transposes aligned 2-D tiles, not [1, L] rows)
+    bs = jnp.broadcast_to(b, (L, L))  # bs[t, s] = b_s
+    bt = bs.T  # bt[t, s] = b_t
     # intra-chunk: D[t, s] = exp(b_t - b_s) * i_s  for s <= t else 0
-    bt = b[:, None]
-    bs = b[None, :]
     causal = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= jax.lax.broadcasted_iota(
         jnp.int32, (L, L), 1
     )
-    decay = jnp.where(causal, jnp.exp(bt - bs), 0.0) * ig[None, :]
+    decay = jnp.where(causal, jnp.exp(bt - bs), 0.0) * ig
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [L, L]
     intra = (s * decay) @ v_aug  # [L, dv+1]
 
     # inter-chunk: exp(b_t) * q_t @ C_carry
-    inter = jnp.exp(bt) * jax.lax.dot_general(
+    inter = jnp.exp(bt[:, :1]) * jax.lax.dot_general(
         q, C_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # [L, dv+1]
 
@@ -104,9 +101,9 @@ def _gla_kernel(
     o_ref[0, ...] = out.astype(o_ref.dtype)
 
     # state update: C_new = exp(b_L) * C + sum_s exp(b_L - b_s) i_s k_s v_aug_sT
-    b_last = b[L - 1]
-    w = jnp.exp(b_last - b) * ig  # [L]
-    kw = k * w[:, None]  # [L, dk]
+    b_last = b[:, L - 1:]  # [1, 1]
+    w = jnp.exp(b_last - b) * ig  # [1, L]
+    kw = k * jnp.broadcast_to(w, (L, L)).T[:, :1]  # [L, dk]
     C_ref[...] = jnp.exp(b_last) * C_ref[...] + jax.lax.dot_general(
         kw, v_aug, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -127,9 +124,10 @@ def gla_scan(
     i_gate: jax.Array,  # [B, H, T]
     normalize: bool = True,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
-    """Returns (out [B, H, T, dv], final_state [B, H, dk, dv+1])."""
+    """Returns (out [B, H, T, dv], final_state [B, H, dk, dv+1]).  Compiled
+    for the TPU by default; ``interpret=True`` runs it on the CPU."""
     B, H, T, dk = q.shape
     dv = v.shape[-1]
     L = min(chunk, T)
@@ -138,13 +136,16 @@ def gla_scan(
     qr = q.reshape(B * H, T, dk)
     kr = k.reshape(B * H, T, dk)
     vr = v.reshape(B * H, T, dv)
-    lfr = log_f.reshape(B * H, T)
-    igr = i_gate.reshape(B * H, T)
+    # the chunk-local cumsum of the log forget gates runs here in XLA (Mosaic
+    # has no cumsum); the padded tail gets log f = 0, i = 0: identity steps
+    pad = ((0, 0), (0, 0), (0, nc * L - T))
+    lf = jnp.pad(log_f.reshape(B * H, 1, T).astype(jnp.float32), pad)
+    br = jnp.cumsum(lf.reshape(B * H, 1, nc, L), axis=-1).reshape(B * H, 1, nc * L)
+    igr = jnp.pad(i_gate.reshape(B * H, 1, T).astype(jnp.float32), pad)
 
     kernel = functools.partial(
         _gla_kernel,
         L=L,
-        dk=dk,
         dv=dv,
         seq_len=T,
         n_chunks=nc,
@@ -158,8 +159,8 @@ def gla_scan(
             pl.BlockSpec((1, L, dk), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, L, dk), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, L, dv), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, L), lambda bh, ci: (bh, ci)),
-            pl.BlockSpec((1, L), lambda bh, ci: (bh, ci)),
+            pl.BlockSpec((1, 1, L), lambda bh, ci: (bh, 0, ci)),
+            pl.BlockSpec((1, 1, L), lambda bh, ci: (bh, 0, ci)),
         ],
         out_specs=[
             pl.BlockSpec((1, L, dv), lambda bh, ci: (bh, ci, 0)),
@@ -170,9 +171,9 @@ def gla_scan(
             jax.ShapeDtypeStruct((B * H, dk, dv + 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv + 1), jnp.float32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qr, kr, vr, lfr, igr)
+    )(qr, kr, vr, br, igr)
     return out.reshape(B, H, T, dv), state.reshape(B, H, dk, dv + 1)

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat, configs
+from .. import configs
 from ..core.models import V5E
 from ..models import lm
 from ..models.config import ModelConfig
@@ -315,12 +315,12 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: str = "xla",
     chips = int(math.prod(mesh.devices.shape))
     shp = configs.SHAPES[shape_name]
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, args = build_step(cfg, shape_name, mesh, multi_pod, mode, microbatches)
         lowered = step.lower(*args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
 
     if hlo_out:

@@ -12,14 +12,14 @@ jax device state (the dry-run needs to set XLA_FLAGS first).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from .. import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes, auto_axes=True)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -27,7 +27,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"need {data*model} devices, have {n}")
-    return compat.make_mesh((data, model), ("data", "model"), auto_axes=True)
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_shape_dict(mesh) -> dict[str, int]:

@@ -44,6 +44,7 @@ import numpy as np
 from .. import configs
 from ..serving.engine import ContinuousBatchingEngine
 from ..serving.tp_lm import TPServeConfig
+from .compile_cache import enable_compile_cache
 
 
 def _tp_config(cfg, prompt_len: int, max_new: int) -> TPServeConfig:
@@ -321,6 +322,7 @@ def main():
         else:
             _explain(cfg, args)
         return
+    enable_compile_cache()
     if args.dry_run:
         args.requests = min(args.requests, 3)
         args.prompt_len = min(args.prompt_len, 4)

@@ -3,11 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --reduced \
         --steps 50 --batch 8 --seq 128 --mode fmi --allreduce ring
 
-Full-size archs on the production mesh are exercised via dryrun.py (this
-container has one real device); ``--reduced`` trains the smoke-sized config
-of the same family for real.  Supports both distribution modes, gradient
-compression, ZeRO-1, checkpoint/restart (``--ckpt-dir``), and resumes
-automatically from the latest committed checkpoint.
+``--reduced`` trains the CPU-sized config of the same family; without it
+the published widths train, which on one 16 GB TPU v5e fits llama3.2-1b
+with ``--moment-dtype bfloat16`` at batch 1 x 2048 (``chip_smoke.py``).
+``main(argv)`` also runs in-process and returns the run's summary.
+Supports both distribution modes, gradient compression, ZeRO-1,
+checkpoint/restart (``--ckpt-dir``), and resumes automatically from the
+latest committed checkpoint.
 
 Elastic demo: ``--elastic`` arms the runtime's heal path, and
 ``--kill-rank R --kill-at-step N`` injects a deterministic failure —
@@ -29,17 +31,20 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 
-from .. import compat, configs
+from .. import configs
 from ..checkpoint import CheckpointManager
 from ..data.pipeline import DataConfig, synthetic_batch
+from ..kernels import ops
 from ..models import lm
 from ..optim.optimizer import OptConfig
 from ..training.train_step import TrainConfig, init_opt_state, make_train_step, place_state
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 
 
-def main():
+def main(argv: list[str] | None = None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -58,6 +63,10 @@ def main():
     ap.add_argument("--compression", default="none", choices=["none", "int8"])
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
+    ap.add_argument("--moment-dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="Adam moment dtype; bfloat16 lets full-width "
+                    "llama3.2-1b train on one 16 GB chip")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--data-axis", type=int, default=1)
@@ -75,15 +84,18 @@ def main():
     from .sanitize_cli import add_sanitize_args, arm, emit
 
     add_sanitize_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     san = arm(args)  # before the first communicator is built
+    enable_compile_cache()
 
     cfg = configs.get_reduced(args.arch) if args.reduced else configs.get(args.arch)
     mesh = make_host_mesh(args.data_axis, args.model_axis)
     tcfg = TrainConfig(
         mode=args.mode,
         microbatches=args.microbatches,
-        optimizer=OptConfig(lr=args.lr, total_steps=args.steps, warmup_steps=min(20, args.steps // 5 + 1)),
+        optimizer=OptConfig(lr=args.lr, total_steps=args.steps,
+                            warmup_steps=min(20, args.steps // 5 + 1),
+                            state_dtype=args.moment_dtype),
         allreduce=args.allreduce,
         schedule=args.schedule,
         bucket_mb=args.bucket_mb,
@@ -93,8 +105,12 @@ def main():
     step_fn, ax, pspecs = make_train_step(cfg, tcfg, mesh, multi_pod=False)
     dcfg = DataConfig()
 
-    with compat.set_mesh(mesh):
-        params = lm.init_params(cfg, jax.random.key(0))
+    with jax.set_mesh(mesh):
+        # params reach their shardings before the moments exist: at full
+        # width f32 params and f32 moments do not fit one chip together
+        params = jax.device_put(
+            lm.init_params(cfg, jax.random.key(0)),
+            jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs))
         if args.zero1 and args.mode == "fmi":
             from ..core.communicator import Communicator
             from ..training import zero1 as z1
@@ -105,8 +121,7 @@ def main():
             opt_state = z1.zero1_init(params, layout, comm, tcfg.optimizer.state_dtype)
         else:
             opt_state = init_opt_state(cfg, tcfg, params)
-        if not args.zero1:
-            params, opt_state = place_state(mesh, params, opt_state, pspecs, tcfg)
+        params, opt_state = place_state(mesh, params, opt_state, pspecs, tcfg)
 
         start = 0
         ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
@@ -156,6 +171,21 @@ def main():
                 strategy=args.regroup,
             )
 
+        def batch_at(step):
+            return jax.tree.map(
+                jax.numpy.asarray,
+                synthetic_batch(dcfg, cfg, args.batch, args.seq, step),
+            )
+
+        # compile once, ahead of the loop, so compile time is reported apart
+        # from the step times and the compiled program can be inspected
+        t0 = time.perf_counter()
+        step_fn = step_fn.lower(params, opt_state, batch_at(start)).compile()
+        compile_s = time.perf_counter() - t0
+        mosaic_calls = step_fn.as_text().count('custom_call_target="tpu_custom_call"')
+        print(f"compiled step in {compile_s:.1f}s; attention backend "
+              f"{ops.flash_backend()}; Mosaic kernels in the step: {mosaic_calls}")
+
         history = []
         t_start = time.perf_counter()
         step, end = start, start + args.steps
@@ -178,13 +208,10 @@ def main():
                           f"({h['strategy']}, spares={h['spares']}), "
                           f"resuming at step {step}")
                     continue
-            batch = jax.tree.map(
-                jax.numpy.asarray,
-                synthetic_batch(dcfg, cfg, args.batch, args.seq, step),
-            )
+            batch = batch_at(step)
             t0 = time.perf_counter()
             params, opt_state, metrics = step_fn(params, opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
+            jax.block_until_ready((params, opt_state, metrics))
             dt = time.perf_counter() - t0
             m = {k: float(v) for k, v in metrics.items()}
             history.append({"step": step, "time_s": dt, **m})
@@ -211,6 +238,9 @@ def main():
         with open(args.out_json, "w") as f:
             json.dump(history, f)
     emit(san, args)
+    return {"history": history, "compile_s": compile_s,
+            "attention_backend": ops.flash_backend(),
+            "mosaic_calls": mosaic_calls}
 
 
 if __name__ == "__main__":

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..kernels import ops
 from .config import ModelConfig
@@ -149,20 +150,42 @@ def attn_apply(
             # sequence-sharded cache — measured in the §Perf log)
             out = _full_cache_attend(q, new_cache, cfg, decode_pos, window)
     else:
-        src_k, src_v = k, v
-        out = ops.flash_attention(
-            jnp.swapaxes(q, 1, 2),
-            jnp.swapaxes(src_k, 1, 2),
-            jnp.swapaxes(src_v, 1, 2),
-            causal=causal, window=window, q_offset=0, backend=backend,
-        )
-        out = jnp.swapaxes(out, 1, 2)
+        out = flash_attend(q, k, v, ax, causal=causal, window=window,
+                           backend=backend)
 
     out = ax.act_bthd(out)
     out = _merge_heads(out) @ p["wo"].astype(cfg.adtype)
     if cross:
         out = jnp.tanh(p["gate"].astype(jnp.float32)).astype(out.dtype) * out
     return ax.act_btd(out), new_cache
+
+
+def flash_attend(q, k, v, ax: Axes, *, causal: bool, window: int = 0,
+                 backend: str = "auto"):
+    """Train/prefill attention, q [B,T,Hq,hd], k/v [B,S,Hkv,hd] ->
+    [B,T,Hq,hd], through :func:`repro.kernels.ops.flash_attention`.
+
+    A Mosaic kernel runs once per shard of the ambient mesh: batch over the
+    data axes, heads over the model axis where both head counts divide.
+    Inside a ``shard_map`` that leaves an axis automatic (fmi-mode training
+    of a tensor-parallel model) no map can hold the kernel, and the step
+    runs the XLA twin (:func:`repro.kernels.ops.per_shard`)."""
+
+    def attend(q, k, v, backend=backend):
+        out = ops.flash_attention(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+            causal=causal, window=window, backend=backend,
+        )
+        return jnp.swapaxes(out, 1, 2)
+
+    if ops.flash_backend(backend) not in ("pallas", "interpret"):
+        return attend(q, k, v)
+    heads = ax.model if (ax.model and ax.divides(q.shape[2], ax.model)
+                         and ax.divides(k.shape[2], ax.model)) else None
+    mapped = ops.per_shard(attend, P(ax.data or None, None, heads, None))
+    if mapped is None:
+        return attend(q, k, v, backend="xla")
+    return mapped(q, k, v)
 
 
 def _full_cache_attend(q, cache, cfg: ModelConfig, t, window: int):

@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops
+from .attention import flash_attend
 from .config import ModelConfig
 from .layers import Axes, apply_rope, dense_init, rmsnorm
 
@@ -130,13 +130,7 @@ def mla_apply(
             axis=-1,
         )
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
-        out = ops.flash_attention(
-            jnp.swapaxes(q, 1, 2),
-            jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2),
-            causal=True, backend=backend,
-        )
-        out = jnp.swapaxes(out, 1, 2)
+        out = flash_attend(q, k, v, ax, causal=True, backend=backend)
 
     out = ax.act_bthd(out)
     out = out.reshape(B, T, H * m.v_dim) @ p["wo"].astype(dt)

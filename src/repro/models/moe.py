@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .config import ModelConfig
 from .layers import Axes, dense_init, swiglu
 
@@ -272,7 +271,7 @@ def _moe_fmi(p, x, idx, w, pos, keep, cfg: ModelConfig, ax: Axes, C: int,
         # all-reduces; the dry-run disables that pass (see launch/dryrun.py).
         return jax.lax.psum(part, ax.model)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         in_specs=(tok_spec, tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
         out_specs=tok_spec,

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..core import collectives as C
 from ..core import compression as COMP
 from ..core.communicator import Communicator
@@ -226,7 +225,8 @@ def make_train_step_fmi(cfg: ModelConfig, tcfg: TrainConfig, mesh, multi_pod: bo
     def reduce_grads(grads):
         if tcfg.compression == "int8":
             t = comm_data.transport()
-            flats = zero1.flatten_groups(grads, zero1.make_layout(grads, 1))
+            lay = zero1.make_flat_layout(grads)
+            flats = zero1.flatten_groups(grads, lay)
             out = []
             for f in flats:
                 n = f.shape[0]
@@ -236,7 +236,6 @@ def make_train_step_fmi(cfg: ModelConfig, tcfg: TrainConfig, mesh, multi_pod: bo
                     t, f2.astype(jnp.float32), op="add", block=256, mean=True
                 )
                 out.append(r[:n].astype(f.dtype))
-            lay = zero1.make_layout(grads, 1)
             return zero1.unflatten_groups(out, lay)
         if tcfg.hierarchical and comm_pod is not None:
             def one(g):
@@ -296,7 +295,7 @@ def make_train_step_fmi(cfg: ModelConfig, tcfg: TrainConfig, mesh, multi_pod: bo
     else:
         opt_shapes = jax.eval_shape(lambda: adamw_init(pshapes, tcfg.optimizer))
 
-    step = compat.shard_map(
+    step = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(spec_tree(pshapes), spec_tree(opt_shapes), batch_specs),

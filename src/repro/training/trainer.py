@@ -39,7 +39,6 @@ from typing import Callable, Sequence
 import jax
 import numpy as np
 
-from .. import compat
 from ..checkpoint import CheckpointManager
 from ..data.pipeline import DataConfig, Pipeline, synthetic_batch
 from ..models import lm
@@ -93,7 +92,7 @@ class Trainer:
     def init_state(self, seed: int = 0):
         from .train_step import place_state
 
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = lm.init_params(self.cfg, jax.random.key(seed))
             opt = init_opt_state(self.cfg, self.tcfg, params)
             params, opt = place_state(self.mesh, params, opt, self.pspecs, self.tcfg)
@@ -112,7 +111,7 @@ class Trainer:
         """Reshard: latest committed checkpoint re-placed onto the rebuilt
         mesh (falls back to re-initialization at step 0 when nothing was
         committed yet)."""
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             if self.ckpt is not None:
                 self.ckpt.wait()
                 try:
@@ -163,7 +162,7 @@ class Trainer:
                     self._restored_state = None
                     step = resume
                     continue
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 batch = synthetic_batch(
                     self.data_cfg, self.cfg, self.batch, self.seq, step
                 )

@@ -26,7 +26,6 @@ from repro.models import lm
 from repro.models.layers import NO_SHARD
 
 
-from repro.compat import cost_analysis as _cost_analysis
 
 
 def test_scan_counted_once_by_xla():
@@ -41,7 +40,7 @@ def test_scan_counted_once_by_xla():
                 return x
             return jax.lax.scan(lambda c, _: (jnp.tanh(c @ w), None), x, None,
                                 length=n)[0]
-        return _cost_analysis(jax.jit(f).lower(w, x).compile())["flops"]
+        return jax.jit(f).lower(w, x).compile().cost_analysis()["flops"]
 
     assert mk(8, True) > 7 * mk(8, False)  # scan body counted once
 
@@ -59,7 +58,7 @@ def test_analytic_flops_vs_unrolled_hlo(arch):
         logits, _, _ = lm.forward(params, cfg, NO_SHARD, batch)
         return logits
 
-    hlo_flops = _cost_analysis(jax.jit(fwd).lower(pshapes, bshapes).compile())["flops"]
+    hlo_flops = jax.jit(fwd).lower(pshapes, bshapes).compile().cost_analysis()["flops"]
     tokens = B * S
     analytic = (
         layer_flops_per_tok(cfg, S / 2, S) * cfg.n_layers * tokens

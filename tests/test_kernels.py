@@ -1,24 +1,24 @@
 """Per-kernel validation: Pallas (interpret=True) and the xla backends vs
 the pure-jnp oracles in repro/kernels/ref.py, swept over shapes/dtypes."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# Without an accelerator every kernel runs in pallas interpret mode
-# (interpret=True below); if pallas itself cannot even be imported on this
-# jax/platform combination, skip the module with the reason rather than
-# erroring — the kernels are exercised for real on TPU builds.
-try:
-    from repro.kernels import ops, ref
-    from repro.kernels.flash_attention import flash_attention as fa_pallas
-    from repro.kernels.quantize import dequantize_blockwise as dq_pallas
-    from repro.kernels.quantize import quantize_blockwise as q_pallas
-    from repro.kernels.ssm_scan import gla_scan as gla_pallas
-except (ImportError, AttributeError) as e:  # pragma: no cover - env-specific
-    pytest.skip(f"pallas unavailable on this jax/platform: {e!r}; "
-                "kernel validation needs pallas interpret mode",
-                allow_module_level=True)
+from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention as fa_pallas
+from repro.kernels.paged_attention import paged_attention
+from repro.kernels.quantize import dequantize_blockwise as dq_pallas
+from repro.kernels.quantize import dequantize_page as dqp_pallas
+from repro.kernels.quantize import quantize_blockwise as q_pallas
+from repro.kernels.quantize import quantize_page as qp_pallas
+from repro.kernels.ssm_scan import gla_scan as gla_pallas
+
+# The raw kernels compile for the TPU by default; on the CPU each call below
+# runs the kernel body in the Pallas interpreter (interpret=True).
+pa_pallas = functools.partial(paged_attention, interpret=True)
 
 rng = np.random.default_rng(0)
 
@@ -147,10 +147,6 @@ def test_quantize_zero_block_is_exact():
 # ---------------------------------------------------------------------------
 # paged decode attention: sweep vs oracles, bitwise invariances, quantized KV
 # ---------------------------------------------------------------------------
-
-from repro.kernels.paged_attention import paged_attention as pa_pallas  # noqa: E402
-from repro.kernels.quantize import dequantize_page as dqp_pallas  # noqa: E402
-from repro.kernels.quantize import quantize_page as qp_pallas  # noqa: E402
 
 # Tolerance tiers (docs/kernels.md):
 #  * unquantized kernel vs the blocked oracle / the unpaged naive reference:

@@ -22,9 +22,9 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro import compat, configs
+    from repro import configs
     from repro.core import collectives as C
     from repro.core.communicator import Communicator
     from repro.data.pipeline import DataConfig, synthetic_batch
@@ -40,16 +40,16 @@ SCRIPT = textwrap.dedent(
             failures.append(name)
 
     # ---- 1. shard_map collectives vs lax references --------------------
-    mesh = compat.make_mesh((8,), ("data",), auto_axes=True)
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     comm = Communicator(axes=("data",), sizes=(8,))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
 
     def run(fn, out_specs=P("data", None)):
-        g = compat.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
+        g = jax.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
                              in_specs=P("data", None), out_specs=out_specs,
                              axis_names={"data"})
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return np.asarray(jax.jit(g)(x))
 
     for algo in ("ring", "rabenseifner", "recursive_doubling", "xla"):
@@ -58,6 +58,18 @@ SCRIPT = textwrap.dedent(
 
     got = run(lambda v: C.reduce_scatter(v, comm, algorithm="recursive_halving"))
     check("reduce_scatter", np.allclose(got, x.sum(0).reshape(8, 2), atol=1e-4))
+
+    # row blocks (ZeRO-1's layout): split and stacked along the leading axis
+    for algo in ("recursive_halving", "ring", "xla"):
+        got = run(lambda v, a=algo: C.reduce_scatter(v.reshape(16, 1), comm,
+                                                     algorithm=a, rows=True))
+        check(f"reduce_scatter rows/{algo}",
+              np.allclose(got, x.sum(0).reshape(8, 2, 1), atol=1e-4))
+    for algo in ("recursive_doubling", "ring", "xla"):
+        got = run(lambda v, a=algo: C.allgather(v.reshape(2, 8), comm,
+                                                algorithm=a, rows=True))
+        check(f"allgather rows/{algo}",
+              all(np.array_equal(g, np.asarray(x).reshape(16, 8)) for g in got))
 
     got = run(lambda v: C.scan(v, comm))
     check("scan", np.allclose(got, np.cumsum(x, 0), atol=1e-4))
@@ -76,11 +88,11 @@ SCRIPT = textwrap.dedent(
             out = C.allreduce_tree(tr, comm, algorithm="recursive_doubling",
                                    mean=True, schedule=schedule, **kw)
             return {k: t[None] for k, t in out.items()}
-        g = compat.shard_map(body, mesh=mesh,
+        g = jax.shard_map(body, mesh=mesh,
                              in_specs=({k: P("data", None) for k in tree},),
                              out_specs={k: P("data", None) for k in tree},
                              axis_names={"data"})
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.tree.map(np.asarray, jax.jit(g)(tree))
     blk = sync("blocking")
     bkt = sync("bucketed", bucket_bytes=16)  # tiny buckets: every leaf its own
@@ -90,13 +102,13 @@ SCRIPT = textwrap.dedent(
     # ---- 2. fmi-mode vs xla-mode training parity -----------------------
     TINY = configs.get_reduced("llama3_2_1b", n_layers=2, d_model=64, n_heads=4,
                                n_kv_heads=2, d_ff=128, vocab_size=256, head_dim=16)
-    mesh2 = compat.make_mesh((4, 2), ("data", "model"), auto_axes=True)
+    mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     opt = OptConfig(lr=1e-3, warmup_steps=0, total_steps=10, clip_norm=0.0)
     dcfg = DataConfig()
 
     def train(tcfg, steps=3):
         step_fn, axx, pspecs = make_train_step(TINY, tcfg, mesh2, False)
-        with compat.set_mesh(mesh2):
+        with jax.set_mesh(mesh2):
             params = lm.init_params(TINY, jax.random.key(0))
             if tcfg.zero1 and tcfg.mode == "fmi":
                 from repro.training import zero1 as z1
@@ -163,10 +175,10 @@ SCRIPT = textwrap.dedent(
 
     tmp = tempfile.mkdtemp()
     tcfg = TrainConfig(mode="xla", optimizer=opt, donate=False)
-    mesh4 = compat.make_mesh((4, 1), ("data", "model"), auto_axes=True,
+    mesh4 = jax.make_mesh((4, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2,
                              devices=jax.devices()[:4])
     step4, _, pspecs4 = make_train_step(TINY, tcfg, mesh4, False)
-    with compat.set_mesh(mesh4):
+    with jax.set_mesh(mesh4):
         params = lm.init_params(TINY, jax.random.key(0))
         opt_state = init_opt_state(TINY, tcfg, params)
         params, opt_state = place_state(mesh4, params, opt_state, pspecs4, tcfg)
@@ -179,10 +191,10 @@ SCRIPT = textwrap.dedent(
         loss_before = float(m["loss"])
 
     # "failure": rebuild on 2 surviving devices, restore, continue
-    mesh2d = compat.make_mesh((2, 1), ("data", "model"), auto_axes=True,
+    mesh2d = jax.make_mesh((2, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2,
                               devices=jax.devices()[:2])
     step2, _, pspecs2 = make_train_step(TINY, tcfg, mesh2d, False)
-    with compat.set_mesh(mesh2d):
+    with jax.set_mesh(mesh2d):
         shapes = jax.eval_shape(lambda: {"params": params, "opt": opt_state})
         state, step = mgr.restore_latest(shapes)
         ok_resume = step == 2

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat, configs
+from repro import configs
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
@@ -24,7 +24,7 @@ def _run(cfg, tcfg, steps=30, batch=8, seq=32, seed=0):
     mesh = make_host_mesh(1, 1)
     step_fn, ax, _ = make_train_step(cfg, tcfg, mesh, multi_pod=False)
     dcfg = DataConfig(seed=seed)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(cfg, jax.random.key(seed))
         opt = init_opt_state(cfg, tcfg, params)
         losses = []
@@ -51,7 +51,7 @@ def test_grad_accum_matches_single_batch():
     s1, _, _ = make_train_step(TINY, t1, mesh, False)
     s2, _, _ = make_train_step(TINY, t2, mesh, False)
     dcfg = DataConfig()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(TINY, jax.random.key(0))
         o1 = init_opt_state(TINY, t1, params)
         o2 = init_opt_state(TINY, t2, params)
@@ -81,7 +81,7 @@ def test_checkpoint_resume_exact(tmp_path):
             hist.append(float(m["loss"]))
         return params, opt, hist
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = lm.init_params(TINY, jax.random.key(0))
         opt = init_opt_state(TINY, tcfg, params)
         # continuous 10-step run
