@@ -57,6 +57,13 @@ def _observe(op: str, x, comm: Communicator) -> None:
                         _nbytes(x) if x is not None else 0, comm.size)
 
 
+def _scope(op_name: str, algorithm: str):
+    """The named scope of one collective's device ops,
+    ``fmi/<op>/<algorithm>``: a trace puts each op under the algorithm
+    that ran it."""
+    return jax.named_scope(f"fmi/{op_name}/{algorithm}")
+
+
 def _resolve(
     op_name: str, x, comm: Communicator, algorithm: str, objective: str,
     t=None,
@@ -128,18 +135,19 @@ def allreduce(x, comm: Communicator, op="add", algorithm="auto", objective="time
     algorithm, depth = _resolve("allreduce", x, comm, algorithm, objective, t)
     if pipeline is not None:
         depth = int(pipeline)
-    if algorithm == "xla":
-        if not isinstance(op, str) or op not in _XLA_OPS:
-            raise ValueError(f"xla channel supports ops {sorted(_XLA_OPS)}")
-        return _XLA_OPS[op](x, comm.axis_arg)
-    if algorithm in CHUNKED_ALLREDUCE:
-        flat, n = _pad_flat(x, comm.size, t)
-        if depth > 1:
-            out = A.PIPELINED["allreduce"][algorithm](t, flat, op, depth=depth)
-        else:
-            out = A.ALGORITHMS["allreduce"][algorithm](t, flat, op)
-        return _unpad(out, n, x.shape, t)
-    return A.ALGORITHMS["allreduce"][algorithm](t, x, op)
+    with _scope("allreduce", algorithm):
+        if algorithm == "xla":
+            if not isinstance(op, str) or op not in _XLA_OPS:
+                raise ValueError(f"xla channel supports ops {sorted(_XLA_OPS)}")
+            return _XLA_OPS[op](x, comm.axis_arg)
+        if algorithm in CHUNKED_ALLREDUCE:
+            flat, n = _pad_flat(x, comm.size, t)
+            if depth > 1:
+                out = A.PIPELINED["allreduce"][algorithm](t, flat, op, depth=depth)
+            else:
+                out = A.ALGORITHMS["allreduce"][algorithm](t, flat, op)
+            return _unpad(out, n, x.shape, t)
+        return A.ALGORITHMS["allreduce"][algorithm](t, x, op)
 
 
 def reduce_scatter(x, comm: Communicator, op="add", algorithm="auto",
@@ -159,25 +167,26 @@ def reduce_scatter(x, comm: Communicator, op="add", algorithm="auto",
     algorithm, depth = _resolve("reduce_scatter", x, comm, algorithm, "time", t)
     if pipeline is not None:
         depth = int(pipeline)
-    flat = _row_blocks(x, comm.size, t) if rows else _pad_flat(x, comm.size, t)[0]
-    if algorithm == "xla":
-        if op != "add":
-            raise ValueError("xla reduce_scatter supports add")
-        return jax.lax.psum_scatter(x if rows else flat, comm.axis_arg,
-                                    scatter_dimension=0, tiled=True)
-    if algorithm == "recursive_halving":
-        if depth > 1:
-            return A.halving_reduce_scatter_pipelined(t, flat, op, depth=depth)
-        return A.halving_reduce_scatter(t, flat, op)
-    if algorithm == "ring":
-        if depth > 1:
-            chunk = A.ring_reduce_scatter_pipelined(t, flat, op, depth=depth)
-        else:
-            chunk = A.ring_reduce_scatter(t, flat, op)
-        # normalize ring convention (rank r owns chunk (r+1)%P) -> natural
-        P = comm.size
-        perm = [(i, (i + 1) % P) for i in range(P)]
-        return t.ppermute(chunk, perm)
+    with _scope("reduce_scatter", algorithm):
+        flat = _row_blocks(x, comm.size, t) if rows else _pad_flat(x, comm.size, t)[0]
+        if algorithm == "xla":
+            if op != "add":
+                raise ValueError("xla reduce_scatter supports add")
+            return jax.lax.psum_scatter(x if rows else flat, comm.axis_arg,
+                                        scatter_dimension=0, tiled=True)
+        if algorithm == "recursive_halving":
+            if depth > 1:
+                return A.halving_reduce_scatter_pipelined(t, flat, op, depth=depth)
+            return A.halving_reduce_scatter(t, flat, op)
+        if algorithm == "ring":
+            if depth > 1:
+                chunk = A.ring_reduce_scatter_pipelined(t, flat, op, depth=depth)
+            else:
+                chunk = A.ring_reduce_scatter(t, flat, op)
+            # normalize ring convention (rank r owns chunk (r+1)%P) -> natural
+            P = comm.size
+            perm = [(i, (i + 1) % P) for i in range(P)]
+            return t.ppermute(chunk, perm)
     raise ValueError(f"unknown reduce_scatter algorithm {algorithm!r}")
 
 
@@ -203,25 +212,26 @@ def allgather(chunk, comm: Communicator, algorithm="auto", rows: bool = False):
     if algorithm == "auto":
         # doubling is pow2-only; ring handles any rank count
         algorithm = "recursive_doubling" if _is_pow2(comm.size) else "ring"
-    if algorithm == "xla":
-        return jax.lax.all_gather(chunk if rows else chunk.reshape(-1),
-                                  comm.axis_arg, tiled=True)
-    t = comm.transport()
-    fn = (
-        A.doubling_allgather
-        if algorithm == "recursive_doubling"
-        else A.allgather_natural_ring
-    )
-    if rows:
-        if t.stacked or chunk.ndim < 2:
-            raise ValueError(f"rows=True needs a mesh transport and a 2-D or "
-                             f"wider chunk; got shape {tuple(chunk.shape)}")
-        return fn(t, chunk).reshape((-1,) + tuple(chunk.shape[1:]))
-    if t.stacked:
-        out = fn(t, t.xp.reshape(t.xp.asarray(chunk), (t.size, -1)))
-        return t.xp.reshape(out, (t.size, -1))
-    out = fn(t, chunk.reshape(-1))
-    return out.reshape(-1)
+    with _scope("allgather", algorithm):
+        if algorithm == "xla":
+            return jax.lax.all_gather(chunk if rows else chunk.reshape(-1),
+                                      comm.axis_arg, tiled=True)
+        t = comm.transport()
+        fn = (
+            A.doubling_allgather
+            if algorithm == "recursive_doubling"
+            else A.allgather_natural_ring
+        )
+        if rows:
+            if t.stacked or chunk.ndim < 2:
+                raise ValueError(f"rows=True needs a mesh transport and a 2-D or "
+                                 f"wider chunk; got shape {tuple(chunk.shape)}")
+            return fn(t, chunk).reshape((-1,) + tuple(chunk.shape[1:]))
+        if t.stacked:
+            out = fn(t, t.xp.reshape(t.xp.asarray(chunk), (t.size, -1)))
+            return t.xp.reshape(out, (t.size, -1))
+        out = fn(t, chunk.reshape(-1))
+        return out.reshape(-1)
 
 
 def alltoall(x, comm: Communicator, algorithm="auto"):

@@ -201,10 +201,6 @@ class Transport:
     def ones(self, shape: tuple[int, ...], dtype):
         raise NotImplementedError
 
-    # -- instrumentation (no-ops on jax) ------------------------------------
-    def tick(self, nbytes_per_rank: int, participants: int | None = None):
-        """Record one communication round moving ``nbytes_per_rank`` bytes."""
-
     # logical shape (without the stacked rank axis)
     def lshape(self, x) -> tuple[int, ...]:
         raise NotImplementedError
@@ -454,10 +450,6 @@ class SimTransport(Transport):
 
     def lshape(self, x):
         return tuple(x.shape[1:])
-
-    def tick(self, nbytes_per_rank: int, participants: int | None = None):
-        n = participants if participants is not None else self.size
-        self.trace.record(nbytes_per_rank, n)
 
 
 # ---------------------------------------------------------------------------

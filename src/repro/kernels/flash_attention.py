@@ -176,5 +176,6 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     return out.reshape(B, Hq, T, dv)

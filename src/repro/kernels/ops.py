@@ -148,12 +148,13 @@ def _kernel_flash_bwd(causal, window, q_offset, bq, bk, interpret, res, g):
     # saving them, which is flash attention's backward.  It walks the
     # kernel's kv block size, which bounds its [T, bk] f32 tiles.
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: _xla_flash_attention(q, k, v, causal, window, q_offset,
-                                             bk=bk),
-        q, k, v,
-    )
-    return vjp(g)
+    with jax.named_scope("flash_bwd"):
+        _, vjp = jax.vjp(
+            lambda q, k, v: _xla_flash_attention(q, k, v, causal, window, q_offset,
+                                                 bk=bk),
+            q, k, v,
+        )
+        return vjp(g)
 
 
 _kernel_flash.defvjp(_kernel_flash_fwd, _kernel_flash_bwd)

@@ -11,6 +11,14 @@ Supports both distribution modes, gradient compression, ZeRO-1,
 checkpoint/restart (``--ckpt-dir``), and resumes automatically from the
 latest committed checkpoint.
 
+Profiling: ``--profile-dir DIR`` traces ``--profile-steps`` steps after the
+first (which may compile) with ``jax.profiler`` into ``DIR``.  Each step is a
+``train`` step annotation; the batch, checkpoint and heal paths are
+``fmi.input``, ``fmi.checkpoint`` and ``fmi.heal`` spans on the trace's
+clock, and the device ops carry the program's named scopes (``embed``,
+``attention``, ``mlp``, ``flash_fwd``, ``flash_bwd``, ``head_loss``,
+``optimizer``, ``fmi/<op>/<algorithm>``; docs/profiling.md).
+
 Elastic demo: ``--elastic`` arms the runtime's heal path, and
 ``--kill-rank R --kill-at-step N`` injects a deterministic failure —
 at step N rank R is declared dead, the :class:`ElasticController` runs
@@ -73,6 +81,10 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--out-json", default="")
+    ap.add_argument("--profile-dir", default="",
+                    help="trace --profile-steps steps after the first with "
+                    "jax.profiler into this directory")
+    ap.add_argument("--profile-steps", type=int, default=3)
     ap.add_argument("--elastic", action="store_true",
                     help="arm the elastic heal path (membership + controller)")
     ap.add_argument("--regroup", default="pow2_floor",
@@ -187,9 +199,23 @@ def main(argv: list[str] | None = None) -> dict:
               f"{ops.flash_backend()}; Mosaic kernels in the step: {mosaic_calls}")
 
         history = []
+        profiling = "off" if args.profile_dir else "done"
+
+        def profile():
+            """Start the trace after the first step; stop it
+            ``--profile-steps`` steps later."""
+            nonlocal profiling
+            if profiling == "off" and len(history) == 1:
+                jax.profiler.start_trace(args.profile_dir)
+                profiling = "on"
+            elif profiling == "on" and len(history) >= 1 + args.profile_steps:
+                jax.profiler.stop_trace()
+                profiling = "done"
+
         t_start = time.perf_counter()
         step, end = start, start + args.steps
         while step < end:
+            profile()
             state["step_cursor"] = step
             if controller is not None:
                 try:
@@ -201,18 +227,21 @@ def main(argv: list[str] | None = None) -> dict:
                     membership.check_alive()
                 except GroupError as e:
                     print(f"step {step:5d} FAILURE: {e}")
-                    step = controller.heal()
+                    with jax.profiler.TraceAnnotation("fmi.heal"):
+                        step = controller.heal()
                     params, opt_state = state["params"], state["opt"]
                     h = controller.history[-1]
                     print(f"healed: regrouped to dp={h['dp']} "
                           f"({h['strategy']}, spares={h['spares']}), "
                           f"resuming at step {step}")
                     continue
-            batch = batch_at(step)
-            t0 = time.perf_counter()
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-            jax.block_until_ready((params, opt_state, metrics))
-            dt = time.perf_counter() - t0
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with jax.profiler.TraceAnnotation("fmi.input"):
+                    batch = batch_at(step)
+                t0 = time.perf_counter()
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
+                jax.block_until_ready((params, opt_state, metrics))
+                dt = time.perf_counter() - t0
             m = {k: float(v) for k, v in metrics.items()}
             history.append({"step": step, "time_s": dt, **m})
             state["params"], state["opt"] = params, opt_state
@@ -222,12 +251,15 @@ def main(argv: list[str] | None = None) -> dict:
             if ckpt is not None and (step + 1) % args.ckpt_every == 0:
                 world = (len(membership.group()) if controller is not None
                          else args.data_axis * args.model_axis)
-                ckpt.save_async(
-                    {"params": params, "opt": opt_state}, step + 1,
-                    extra={"generation": controller.generation if controller
-                           else 0, "world": world},
-                )
+                with jax.profiler.TraceAnnotation("fmi.checkpoint"):
+                    ckpt.save_async(
+                        {"params": params, "opt": opt_state}, step + 1,
+                        extra={"generation": controller.generation if controller
+                               else 0, "world": world},
+                    )
             step += 1
+        if profiling == "on":
+            jax.profiler.stop_trace()
         if ckpt is not None:
             ckpt.wait()
 

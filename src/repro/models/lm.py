@@ -203,14 +203,16 @@ def param_specs(cfg: ModelConfig, ax: Axes, mesh_shape: dict[str, int] | None = 
 
 
 def _dense_block(p, x, cfg, ax, cache, decode_pos, positions, kv_src=None):
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    a, cache = ATT.attn_apply(
-        p["attn"], h, cfg, ax, kv_src=kv_src, positions=positions,
-        cache=cache, decode_pos=decode_pos,
-    )
-    x = x + a
-    h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    x = x + MOE.mlp_apply(p["mlp"], h, cfg, ax)
+    with jax.named_scope("attention"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        a, cache = ATT.attn_apply(
+            p["attn"], h, cfg, ax, kv_src=kv_src, positions=positions,
+            cache=cache, decode_pos=decode_pos,
+        )
+        x = x + a
+    with jax.named_scope("mlp"):
+        h = rmsnorm(x, p["ln2"], cfg.norm_eps)
+        x = x + MOE.mlp_apply(p["mlp"], h, cfg, ax)
     return ax.act_btd(x), cache
 
 
@@ -318,13 +320,14 @@ def _apply_group(gp, x, cfg: ModelConfig, ax: Axes, cache_g, decode_pos, positio
 
 def _embed_in(params, cfg: ModelConfig, ax: Axes, batch):
     dt = cfg.adtype
-    if cfg.family == "audio":
-        x = batch["features"].astype(dt)
-        mask = batch["mask"][..., None]
-        x = jnp.where(mask, params["mask_emb"].astype(dt), x)
-    else:
-        x = jnp.take(params["embed"], batch["tokens"], axis=0).astype(dt)
-    return ax.act_btd(x)
+    with jax.named_scope("embed"):
+        if cfg.family == "audio":
+            x = batch["features"].astype(dt)
+            mask = batch["mask"][..., None]
+            x = jnp.where(mask, params["mask_emb"].astype(dt), x)
+        else:
+            x = jnp.take(params["embed"], batch["tokens"], axis=0).astype(dt)
+        return ax.act_btd(x)
 
 
 def _head_out(params, cfg: ModelConfig, ax: Axes, x):
@@ -387,7 +390,8 @@ def forward(
             else None
         )
 
-    logits = _head_out(params, cfg, ax, x)
+    with jax.named_scope("head_loss"):
+        logits = _head_out(params, cfg, ax, x)
     return logits, aux, new_cache
 
 

@@ -65,35 +65,36 @@ def clip_by_global_norm(grads, max_norm: float):
 
 def adamw_update(grads, state, params, cfg: OptConfig):
     """Returns (new_params, new_state, metrics)."""
-    grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
-    step = state["step"] + 1
-    lr = lr_at(cfg, state["step"])
-    b1, b2 = cfg.beta1, cfg.beta2
-    c1 = 1 - b1 ** step.astype(jnp.float32)
-    c2 = 1 - b2 ** step.astype(jnp.float32)
+    with jax.named_scope("optimizer"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
+        step = state["step"] + 1
+        lr = lr_at(cfg, state["step"])
+        b1, b2 = cfg.beta1, cfg.beta2
+        c1 = 1 - b1 ** step.astype(jnp.float32)
+        c2 = 1 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        gf = g.astype(jnp.float32)
-        mf = b1 * m.astype(jnp.float32) + (1 - b1) * gf
-        vf = b2 * v.astype(jnp.float32) + (1 - b2) * gf * gf
-        mh = mf / c1
-        vh = vf / c2
-        step_ = mh / (jnp.sqrt(vh) + cfg.eps)
-        pf = p.astype(jnp.float32)
-        if p.ndim >= 2:  # decoupled weight decay on matrices only
-            step_ = step_ + cfg.weight_decay * pf
+        def upd(p, g, m, v):
+            gf = g.astype(jnp.float32)
+            mf = b1 * m.astype(jnp.float32) + (1 - b1) * gf
+            vf = b2 * v.astype(jnp.float32) + (1 - b2) * gf * gf
+            mh = mf / c1
+            vh = vf / c2
+            step_ = mh / (jnp.sqrt(vh) + cfg.eps)
+            pf = p.astype(jnp.float32)
+            if p.ndim >= 2:  # decoupled weight decay on matrices only
+                step_ = step_ + cfg.weight_decay * pf
+            return (
+                (pf - lr * step_).astype(p.dtype),
+                mf.astype(m.dtype),
+                vf.astype(v.dtype),
+            )
+
+        out = jax.tree.map(upd, params, grads, state["m"], state["v"])
+        new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda t: isinstance(t, tuple))
+        new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda t: isinstance(t, tuple))
+        new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda t: isinstance(t, tuple))
         return (
-            (pf - lr * step_).astype(p.dtype),
-            mf.astype(m.dtype),
-            vf.astype(v.dtype),
+            new_params,
+            {"m": new_m, "v": new_v, "step": step},
+            {"lr": lr, "grad_norm": gnorm},
         )
-
-    out = jax.tree.map(upd, params, grads, state["m"], state["v"])
-    new_params = jax.tree.map(lambda t: t[0], out, is_leaf=lambda t: isinstance(t, tuple))
-    new_m = jax.tree.map(lambda t: t[1], out, is_leaf=lambda t: isinstance(t, tuple))
-    new_v = jax.tree.map(lambda t: t[2], out, is_leaf=lambda t: isinstance(t, tuple))
-    return (
-        new_params,
-        {"m": new_m, "v": new_v, "step": step},
-        {"lr": lr, "grad_norm": gnorm},
-    )

@@ -68,7 +68,8 @@ def _axes_for(cfg: ModelConfig, mesh, multi_pod: bool, global_batch=None) -> Axe
 
 def _loss(params, cfg: ModelConfig, ax: Axes, batch):
     logits, aux, _ = lm.forward(params, cfg, ax, batch)
-    loss, ce = lm.loss_fn(logits, batch["labels"], cfg, aux)
+    with jax.named_scope("head_loss"):
+        loss, ce = lm.loss_fn(logits, batch["labels"], cfg, aux)
     return loss, ce
 
 

@@ -136,60 +136,61 @@ def zero1_update(grads, state, params, layout: RowLayout, comm: Communicator,
     (manual over comm.axes)."""
     from ..optim.optimizer import lr_at
 
-    g_rows = [_row_view(g, layout, i) for i, g in enumerate(jax.tree.leaves(grads))]
-    p_rows = [_row_view(p, layout, i) for i, p in enumerate(jax.tree.leaves(params))]
-    P = comm.size
+    with jax.named_scope("optimizer"):
+        g_rows = [_row_view(g, layout, i) for i, g in enumerate(jax.tree.leaves(grads))]
+        p_rows = [_row_view(p, layout, i) for i, p in enumerate(jax.tree.leaves(params))]
+        P = comm.size
 
-    step = state["step"] + 1
-    lr = lr_at(opt_cfg, state["step"])
-    b1, b2 = opt_cfg.beta1, opt_cfg.beta2
-    c1 = 1 - b1 ** step.astype(jnp.float32)
-    c2 = 1 - b2 ** step.astype(jnp.float32)
+        step = state["step"] + 1
+        lr = lr_at(opt_cfg, state["step"])
+        b1, b2 = opt_cfg.beta1, opt_cfg.beta2
+        c1 = 1 - b1 ** step.astype(jnp.float32)
+        c2 = 1 - b2 ** step.astype(jnp.float32)
 
-    # phase 1: reduce-scatter every leaf through the request layer,
-    # issue-all-then-waitall — no program-order barrier between leaves
-    # (see the module docstring for what overlap this does and does not buy)
-    rs_reqs = [
-        R.ireduce_scatter(g, comm, op="add", algorithm=algorithm, rows=True)
-        for g in g_rows
-    ]
-    chunks = [c / P if mean else c for c in R.waitall(rs_reqs)]
+        # phase 1: reduce-scatter every leaf through the request layer,
+        # issue-all-then-waitall — no program-order barrier between leaves
+        # (see the module docstring for what overlap this does and does not buy)
+        rs_reqs = [
+            R.ireduce_scatter(g, comm, op="add", algorithm=algorithm, rows=True)
+            for g in g_rows
+        ]
+        chunks = [c / P if mean else c for c in R.waitall(rs_reqs)]
 
-    # global-norm clip on the *reduced* gradient: each rank owns 1/P of
-    # every leaf, so the global sq-norm is an allreduce of chunk sq-norms
-    gnorm = jnp.zeros((), jnp.float32)
-    if opt_cfg.clip_norm:
-        local_sq = sum(jnp.sum(jnp.square(c.astype(jnp.float32))) for c in chunks)
-        total_sq = C.allreduce(local_sq[None], comm, algorithm="recursive_doubling")[0]
-        gnorm = jnp.sqrt(total_sq)
-        scale = jnp.minimum(1.0, opt_cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
-        chunks = [(c.astype(jnp.float32) * scale).astype(c.dtype) for c in chunks]
+        # global-norm clip on the *reduced* gradient: each rank owns 1/P of
+        # every leaf, so the global sq-norm is an allreduce of chunk sq-norms
+        gnorm = jnp.zeros((), jnp.float32)
+        if opt_cfg.clip_norm:
+            local_sq = sum(jnp.sum(jnp.square(c.astype(jnp.float32))) for c in chunks)
+            total_sq = C.allreduce(local_sq[None], comm, algorithm="recursive_doubling")[0]
+            gnorm = jnp.sqrt(total_sq)
+            scale = jnp.minimum(1.0, opt_cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
+            chunks = [(c.astype(jnp.float32) * scale).astype(c.dtype) for c in chunks]
 
-    # phase 2: sharded AdamW per leaf, then the allgather of every updated
-    # row block through the request layer, all issued before any is waited on
-    new_m, new_v, ag_reqs = [], [], []
-    try:
-        r = comm.transport().rank()
-        for i, (chunk, pr) in enumerate(zip(chunks, p_rows)):
-            own = jax.lax.dynamic_slice_in_dim(pr, r * chunk.shape[0], chunk.shape[0])
-            gfl = chunk.astype(jnp.float32)
-            m = b1 * state["m"][i].astype(jnp.float32) + (1 - b1) * gfl
-            v = b2 * state["v"][i].astype(jnp.float32) + (1 - b2) * gfl * gfl
-            upd = (m / c1) / (jnp.sqrt(v / c2) + opt_cfg.eps)
-            upd = upd + opt_cfg.weight_decay * own.astype(jnp.float32)
-            own_new = (own.astype(jnp.float32) - lr * upd).astype(pr.dtype)
-            ag_reqs.append(R.iallgather(own_new, comm, algorithm=ag_algorithm, rows=True))
-            new_m.append(m.astype(state["m"][i].dtype))
-            new_v.append(v.astype(state["v"][i].dtype))
-        gathered = R.waitall(ag_reqs)
-    except BaseException:
-        # a failure mid-issue (e.g. RankFailure) must not strand the already
-        # issued allgathers — cancel them so the elastic quiesce sees a clean
-        # queue instead of stale-generation in-flight requests
-        for req in ag_reqs:
-            req.cancel()
-        raise
-    new_p = [full[: layout.rows[i]].reshape(layout.shapes[i])
-             for i, full in enumerate(gathered)]
-    params_new = jax.tree.unflatten(layout.treedef, new_p)
-    return params_new, {"m": new_m, "v": new_v, "step": step}, {"lr": lr, "grad_norm": gnorm}
+        # phase 2: sharded AdamW per leaf, then the allgather of every updated
+        # row block through the request layer, all issued before any is waited on
+        new_m, new_v, ag_reqs = [], [], []
+        try:
+            r = comm.transport().rank()
+            for i, (chunk, pr) in enumerate(zip(chunks, p_rows)):
+                own = jax.lax.dynamic_slice_in_dim(pr, r * chunk.shape[0], chunk.shape[0])
+                gfl = chunk.astype(jnp.float32)
+                m = b1 * state["m"][i].astype(jnp.float32) + (1 - b1) * gfl
+                v = b2 * state["v"][i].astype(jnp.float32) + (1 - b2) * gfl * gfl
+                upd = (m / c1) / (jnp.sqrt(v / c2) + opt_cfg.eps)
+                upd = upd + opt_cfg.weight_decay * own.astype(jnp.float32)
+                own_new = (own.astype(jnp.float32) - lr * upd).astype(pr.dtype)
+                ag_reqs.append(R.iallgather(own_new, comm, algorithm=ag_algorithm, rows=True))
+                new_m.append(m.astype(state["m"][i].dtype))
+                new_v.append(v.astype(state["v"][i].dtype))
+            gathered = R.waitall(ag_reqs)
+        except BaseException:
+            # a failure mid-issue (e.g. RankFailure) must not strand the already
+            # issued allgathers — cancel them so the elastic quiesce sees a clean
+            # queue instead of stale-generation in-flight requests
+            for req in ag_reqs:
+                req.cancel()
+            raise
+        new_p = [full[: layout.rows[i]].reshape(layout.shapes[i])
+                 for i, full in enumerate(gathered)]
+        params_new = jax.tree.unflatten(layout.treedef, new_p)
+        return params_new, {"m": new_m, "v": new_v, "step": step}, {"lr": lr, "grad_norm": gnorm}

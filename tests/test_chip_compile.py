@@ -14,6 +14,7 @@ every test file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,24 @@ def _shapes(sharding, *specs):
     return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding) for shape, dtype in specs]
 
 
+# the program's named scopes, as the benchmark's trace readers find them
+STEP_SCOPES = ("embed", "attention", "mlp", "flash_bwd", "head_loss", "optimizer",
+               "fmi/reduce_scatter/recursive_halving", "fmi/allgather/recursive_doubling")
+
+
+def _missing_scopes(txt: str, scopes) -> list:
+    """The scopes that no ``op_name`` of the compiled text stands under; a
+    transformation may wrap a scope, as in ``jvp(head_loss)``."""
+    names = set(re.findall(r'op_name="([^"]*)"', txt))
+    return [s for s in scopes
+            if not any(re.search(rf"(^|[/(]){re.escape(s)}($|[/)])", n) for n in names)]
+
+
+def _mosaic_call_names(txt: str) -> list:
+    return re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                      txt, re.M)
+
+
 # llama3.2-1b: 32 q heads over 8 kv heads, head dim 64, bf16, 2048 tokens
 FLASH = [((1, 32, 2048, 64), jnp.bfloat16), ((1, 8, 2048, 64), jnp.bfloat16),
          ((1, 8, 2048, 64), jnp.bfloat16)]
@@ -85,6 +104,9 @@ def test_flash_attention_gradient_compiles(one_chip):
     txt = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                          *_shapes(one_chip, *FLASH))
     assert "tpu_custom_call" in txt
+    assert _missing_scopes(txt, ("flash_fwd", "flash_bwd")) == []
+    names = _mosaic_call_names(txt)
+    assert names and all("flash" in n for n in names)
 
 
 def test_flash_attention_per_shard_under_four_chip_mesh(topo, monkeypatch):
@@ -125,6 +147,9 @@ def test_fmi_zero1_step_compiles_for_four_chips(topo, monkeypatch):
         txt = step.lower(params, opt, {"tokens": tokens, "labels": tokens}).compile().as_text()
     assert "tpu_custom_call" in txt
     assert "collective-permute" in txt
+    assert _missing_scopes(txt, STEP_SCOPES) == []
+    names = _mosaic_call_names(txt)
+    assert names and all("flash" in n for n in names)
 
 
 # qwen3-1.7b: 16 q heads over 8 kv heads, head dim 128, 16-token pages

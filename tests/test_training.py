@@ -111,3 +111,22 @@ def test_trainer_wrapper_runs():
     params, opt, hist = tr.run(params, opt, steps=3)
     assert len(hist) == 3
     assert np.isfinite(hist[-1]["loss"])
+
+
+def test_launcher_traces_the_steps_after_the_first(tmp_path, monkeypatch):
+    """``--profile-dir`` writes a profiler trace of ``--profile-steps`` steps
+    after the first, each a ``train`` step event around its ``fmi.input``."""
+    from jax.profiler import ProfileData
+
+    from repro.launch import train
+
+    monkeypatch.setattr(train, "enable_compile_cache", lambda: "")
+    train.main(["--arch", "llama3.2-1b", "--reduced", "--steps", "3", "--batch", "2",
+                "--seq", "32", "--mode", "fmi", "--profile-dir", str(tmp_path),
+                "--profile-steps", "2"])
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              for line in plane.lines for e in line.events]
+    steps = sorted(dict(e.stats)["step_num"] for e in events if e.name == "train")
+    assert steps == [1, 2]
+    assert sum(e.name == "fmi.input" for e in events) == 2
