@@ -64,8 +64,9 @@ def per_shard(fn, spec: P):
 # ---------------------------------------------------------------------------
 
 
-def _xla_flash_attention(q, k, v, causal=True, window=0, q_offset=0, bk=512):
-    """Chunked online-softmax attention in pure jnp (lax.scan over kv blocks).
+def _xla_flash_attention(q, k, v, causal=True, window=0, q_offset=0):
+    """Chunked online-softmax attention in pure jnp (lax.scan over kv blocks
+    of 512).
 
     O(T·bk) live memory instead of O(T·S); numerics identical to flash.
     Inputs stay in their storage dtype (bf16): scores/accumulators get f32
@@ -76,7 +77,7 @@ def _xla_flash_attention(q, k, v, causal=True, window=0, q_offset=0, bk=512):
     B, Hq, T, d = q.shape
     _, Hkv, S, dv = v.shape
     group = Hq // Hkv
-    bk = min(bk, S)
+    bk = min(512, S)
     nk = -(-S // bk)
     pad = nk * bk - S
     kf = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -137,27 +138,27 @@ def _kernel_flash(q, k, v, causal, window, q_offset, bq, bk, interpret):
 
 
 def _kernel_flash_fwd(q, k, v, causal, window, q_offset, bq, bk, interpret):
-    out = _kernel_flash(q, k, v, causal, window, q_offset, bq, bk, interpret)
-    return out, (q, k, v)
+    out, lse = _fa.flash_attention_fwd(
+        q, k, v, causal=causal, window=window, q_offset=q_offset,
+        bq=bq, bk=bk, interpret=interpret,
+    )
+    return out, (q, k, v, out, lse)
 
 
 def _kernel_flash_bwd(causal, window, q_offset, bq, bk, interpret, res, g):
-    # The backward pass is the VJP of the chunked XLA twin: it computes the
-    # same function as the kernel with the same f32 online-softmax numerics,
-    # and its per-block checkpoint recomputes the score tiles instead of
-    # saving them, which is flash attention's backward.  It walks the
-    # kernel's kv block size, which bounds its [T, bk] f32 tiles.
-    q, k, v = res
+    # The residuals are the inputs, the output and each row's log-sum-exp
+    # (f32), from which the backward kernels recompute the score tiles.
+    # They choose their own tiles from the shapes; the forward's bq, bk do
+    # not reach them.
     with jax.named_scope("flash_bwd"):
-        _, vjp = jax.vjp(
-            lambda q, k, v: _xla_flash_attention(q, k, v, causal, window, q_offset,
-                                                 bk=bk),
-            q, k, v,
-        )
-        return vjp(g)
+        return _fa.flash_attention_bwd(*res, g, causal=causal, window=window,
+                                       q_offset=q_offset, interpret=interpret)
 
 
-_kernel_flash.defvjp(_kernel_flash_fwd, _kernel_flash_bwd)
+# optimize_remat: where the residuals are dead, as in the forward pass of a
+# rematerialized layer, the primal kernel runs in place of the forward rule,
+# and only the recompute writes lse
+_kernel_flash.defvjp(_kernel_flash_fwd, _kernel_flash_bwd, optimize_remat=True)
 
 
 def flash_backend(backend: str = "auto", q_offset=0) -> str:
@@ -183,7 +184,8 @@ def flash_attention(
     The backend is :func:`flash_backend` ``(backend, q_offset)``: a traced
     ``q_offset`` runs the xla twin.  The dispatch runs in Python, before
     any ``jit``, so a Python-int offset stays static.  The kernel backends
-    are differentiable; their backward pass is the xla twin's.
+    are differentiable; their backward is the Pallas flash backward
+    (:func:`repro.kernels.flash_attention.flash_attention_bwd`).
     """
     backend = flash_backend(backend, q_offset)
     if backend == "xla":

@@ -3,6 +3,7 @@ the pure-jnp oracles in repro/kernels/ref.py, swept over shapes/dtypes."""
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -52,6 +53,36 @@ def test_flash_attention_pallas_vs_ref(case, dtype):
     )
 
 
+GRAD_CASES = [c for c in ATT_CASES if c[3] >= 2]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", GRAD_CASES, ids=[str(c) for c in GRAD_CASES])
+def test_flash_attention_gradient_vs_ref(case, dtype):
+    """The kernel backend's backward (the Pallas dK/dV and dQ kernels) gives
+    the gradient of the f32 oracle: dq, dk and dv."""
+    B, Hq, Hkv, T, S, d, causal, window, off = case
+    r = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(r.normal(size=s), dtype)
+               for s in ((B, Hq, T, d), (B, Hkv, S, d), (B, Hkv, S, d)))
+    w = jnp.asarray(r.normal(size=(B, Hq, T, d)), jnp.float32)
+
+    def grads(attend):
+        loss = lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * w)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=causal, window=window, q_offset=off, backend="interpret"))
+    want = grads(lambda q, k, v: ref.attention(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        causal=causal, window=window, q_offset=off))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for g, x in zip(got, want):
+        assert g.dtype == dtype
+        x = np.asarray(x)
+        np.testing.assert_allclose(np.asarray(g, np.float32), x, atol=tol * np.abs(x).max())
+
+
 @pytest.mark.parametrize("case", ATT_CASES[:4], ids=[str(c) for c in ATT_CASES[:4]])
 def test_flash_attention_xla_backend_vs_ref(case):
     B, Hq, Hkv, T, S, d, causal, window, off = case
@@ -63,8 +94,6 @@ def test_flash_attention_xla_backend_vs_ref(case):
 
 def test_flash_attention_dynamic_offset():
     """decode path: q_offset is traced (jitted position)."""
-    import jax
-
     q, k, v = _mk((1, 4, 1, 32), jnp.float32), _mk((1, 2, 64, 32), jnp.float32), _mk((1, 2, 64, 32), jnp.float32)
 
     @jax.jit
