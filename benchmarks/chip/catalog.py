@@ -4,7 +4,9 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by name:
 
 * ``configs/<config>.json``: the model's sizes as published, with each cut
-  listed, and the name of its plain reference under ``refs/``;
+  listed, and the name of its plain reference under ``refs/``, which says how
+  the sizes become the program's fields, the step's FLOPs and the attention
+  call's work;
 * ``traffic/<traffic>.json``: the batch, the token mix and the training job,
   with the ``kind`` of cell that names its runner, ``runners/<kind>.py``;
 * ``limits/<workload>.json``: the limits of the correctness check;

@@ -4,11 +4,12 @@ Its calls are the Mosaic custom-calls whose name holds ``flash``; the trace
 names the call after the kernel's jitted wrapper.  Each call is one layer's
 causal forward over a chip's rows (remat runs it again in the backward, and
 each run counts).  The share is the least time the chip could take for the
-calls, by ``flops.flash_fwd`` and the peaks, over the time they took, averaged
-over the cell's devices.
+calls, by ``flops.flash_fwd`` at the head widths that the configuration's
+plain reference gives (``attention_dims``) and the peaks, over the time they
+took, averaged over the cell's devices.
 """
 
-from .. import flops
+from .. import catalog, flops
 
 
 def is_flash(op) -> bool:
@@ -18,7 +19,8 @@ def is_flash(op) -> bool:
 
 def read(ctx):
     cell = ctx.cell
-    f, b = flops.flash_fwd(cell.traffic["rows_per_chip"], cell.sizes, cell.traffic["seq_len"])
+    dims = catalog.reference(cell.config).attention_dims(cell.sizes)
+    f, b = flops.flash_fwd(cell.traffic["rows_per_chip"], dims, cell.traffic["seq_len"])
     least = flops.roofline_s(f, b, ctx.peaks)
     shares = []
     for plane in ctx.trace.ops:

@@ -7,6 +7,11 @@ the mean cross-entropy plus ``z_loss`` times the mean squared log-partition.
 AdamW clips the global gradient norm, then decays every leaf but those that
 the training job lists under ``no_decay``.
 
+Beside the computation it says what the harness asks of a reference
+(``refs/__init__.py``): the program's fields for these sizes, the model FLOPs
+of a trained token (``flops.train_flops_per_token``, the dense block's count)
+and the head widths of the attention call.
+
 It imports nothing of the program under test.  Weights come from the
 benchmark's own ``weights.leaf`` and tokens from its own traffic generator.
 Parameters are stored in the dtype that the configuration states and Adam
@@ -39,7 +44,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .. import weights
+from .. import flops, weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = float(jnp.finfo(jnp.float8_e4m3fn).max)
@@ -93,6 +98,22 @@ def layout(s: Sizes) -> dict:
     if not s.tied:
         out["head"] = (D, s.vocab_rows)
     return out
+
+
+def program_fields(s: Sizes) -> dict:
+    """The program's ``ModelConfig`` fields that these sizes set, by name."""
+    return {"n_layers": s.n_layers, "d_model": s.d_model, "n_heads": s.n_heads,
+            "n_kv_heads": s.n_kv_heads, "head_dim": s.head_dim, "d_ff": s.d_ff,
+            "vocab_size": s.vocab, "tie_embeddings": s.tied, "rope_theta": s.rope_theta,
+            "norm_eps": s.norm_eps}
+
+
+def attention_dims(s: Sizes) -> tuple:
+    """``(n_heads, n_kv_heads, d_qk, d_v)`` of a layer's attention call."""
+    return s.n_heads, s.n_kv_heads, s.head_dim, s.head_dim
+
+
+train_flops_per_token = flops.train_flops_per_token  # the dense block's count
 
 
 def lr_at(opt: dict, k: int) -> float:

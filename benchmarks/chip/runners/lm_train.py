@@ -10,7 +10,8 @@ reference, which runs after the window once the program's state is freed.
 
 The cell's configuration file names the program's registry entry
 (``program_arch``) and its plain reference (``reference``, under ``refs/``),
-whose ``Sizes`` read the file's published keys.
+whose ``Sizes`` read the file's published keys and whose ``program_fields``
+put them into the registry's entry.
 """
 
 from __future__ import annotations
@@ -45,14 +46,21 @@ from ..refs.dense_lm import Readings
 
 def model_config(config: dict):
     """The program's ``ModelConfig`` for a configuration file: the registry's
-    entry for ``program_arch`` with the file's sizes and dtypes put in."""
-    s = catalog.reference(config).Sizes.from_config(config)
+    entry for ``program_arch`` with the fields that the file's reference
+    gives for its sizes (``program_fields``) and the file's dtypes put in."""
+    ref = catalog.reference(config)
     prec = config["precision"]
-    return dataclasses.replace(
-        configs.get(config["program_arch"]), n_layers=s.n_layers, d_model=s.d_model,
-        n_heads=s.n_heads, n_kv_heads=s.n_kv_heads, head_dim=s.head_dim, d_ff=s.d_ff,
-        vocab_size=s.vocab, tie_embeddings=s.tied, rope_theta=s.rope_theta,
-        norm_eps=s.norm_eps, dtype=prec["compute_dtype"], param_dtype=prec["param_dtype"])
+    return replace_fields(configs.get(config["program_arch"]), {
+        **ref.program_fields(ref.Sizes.from_config(config)),
+        "dtype": prec["compute_dtype"], "param_dtype": prec["param_dtype"]})
+
+
+def replace_fields(obj, fields: dict):
+    """``dataclasses.replace`` of ``obj`` by ``fields``, where a dict value
+    replaces fields of the nested dataclass of that name in turn."""
+    return dataclasses.replace(obj, **{
+        k: replace_fields(getattr(obj, k), v) if isinstance(v, dict) else v
+        for k, v in fields.items()})
 
 
 def leaf_names(tree) -> list:
@@ -221,7 +229,8 @@ def run_cell(cell: catalog.Cell, seed: int, seconds: float, trace: bool,
 
             t = tr.load(tdir, len(devices))
             ctx = tr.Context(trace=t, cell=cell, spans=spans, window=w,
-                             tokens_per_s=tokens_per_s, peaks=catalog.peaks(kind))
+                             tokens_per_s=tokens_per_s, peaks=catalog.peaks(kind),
+                             profile=tdir)
             metrics = harness.per_layer(cell, ctx)
             device_extra = {"busy_s": t.busy_s(), "window_s": t.window_s()}
             breakdown = t.breakdown()

@@ -8,22 +8,17 @@ A transformation wraps the scope it meets first (``jvp(head_loss)``,
 to the names inside; an op is under a scope wherever the scope's segments
 stand in its path.
 
-A reader is given the loaded trace (``trace.Context.trace``) and not the file
-it came from.  The runner profiles into a fresh directory under the temporary
-directory, so :func:`op_paths` looks there, newest first, for the
-``.xplane.pb`` whose device planes hold every op of the trace.
+A reader is given the loaded trace (``trace.Context.trace``) and the profile
+it was loaded from (``trace.Context.profile``, the directory that the runner
+profiled into), and :func:`op_paths` reads the paths from that same
+``.xplane.pb``.
 """
 
 from __future__ import annotations
 
 import functools
-import glob
-import os
-import tempfile
 
-from . import xspace
-
-PROFILES = ("*", "plugins", "profile", "*", "*.xplane.pb")  # under the temp dir
+from . import trace, xspace
 
 
 def _split(path: str) -> list:
@@ -61,31 +56,22 @@ def under(path: str, name: str) -> bool:
     return any(have[i:i + n] == want for i in range(len(have) - n + 1))
 
 
-def profiles() -> list:
-    """The ``.xplane.pb`` files of profiles under the temporary directory,
-    newest first."""
-    files = glob.glob(os.path.join(tempfile.gettempdir(), *PROFILES))
-    return sorted(files, key=os.path.getmtime, reverse=True)
-
-
 def attach(t, paths: dict) -> dict:
     """Keep ``paths`` (``{plane: {op name: path}}``) as trace ``t``'s."""
     t.op_paths = paths
     return paths
 
 
-def op_paths(t, files=None) -> dict:
-    """``{plane: {op name: path}}`` of trace ``t``, read once from the first
-    of ``files`` (default :func:`profiles`) that holds every op of ``t``;
-    ``{}`` where none does."""
+def op_paths(ctx) -> dict:
+    """``{plane: {op name: path}}`` of ``ctx.trace``, read once from the
+    profile it was loaded from; ``{}`` where the context names none."""
+    t = ctx.trace
     if hasattr(t, "op_paths"):
         return t.op_paths
-    for f in profiles() if files is None else files:
-        paths = xspace.tf_ops(f, keep=set(t.ops).__contains__)
-        if set(paths) == set(t.ops) and all(
-                o.name in paths[p] for p, ops in t.ops.items() for o in ops):
-            return attach(t, paths)
-    return attach(t, {})
+    if ctx.profile is None:
+        return attach(t, {})
+    return attach(t, xspace.tf_ops(trace.xplane_file(ctx.profile),
+                                   keep=set(t.ops).__contains__))
 
 
 def ms_per_step(ctx, select, over: str = "mean") -> float | None:
@@ -93,7 +79,7 @@ def ms_per_step(ctx, select, over: str = "mean") -> float | None:
     op-name path ``select(path)`` picks, the ``mean`` over the cell's devices
     or the ``max``; ``None`` where no op is picked."""
     steps = ctx.window["steps"]
-    paths = op_paths(ctx.trace)
+    paths = op_paths(ctx)
     per = [ctx.trace.op_time(p, lambda o, of=paths.get(p, {}): select(of.get(o.name, "")))
            for p in ctx.trace.ops]
     if not steps or not any(n for _, n in per):

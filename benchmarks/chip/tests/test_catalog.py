@@ -1,6 +1,7 @@
 """Every name in BENCHMARK.json resolves to its file, and each configuration
 file holds the program's published widths, cutting only what it lists."""
 
+import functools
 import importlib
 import json
 
@@ -31,21 +32,45 @@ def test_config_resolves(c):
     assert catalog.reference(config).layout(catalog.reference(config).Sizes.from_config(config))
 
 
+def _flat(fields: dict, prefix: str = "") -> dict:
+    """``{"moe.top_k": 6, ...}``: nested program fields by dotted name."""
+    out = {}
+    for k, v in fields.items():
+        out.update(_flat(v, f"{prefix}{k}.") if isinstance(v, dict) else {prefix + k: v})
+    return out
+
+
+def _field(cfg, dotted: str):
+    return functools.reduce(getattr, dotted.split("."), cfg)
+
+
+def check_published_widths(config: dict):
+    """Every program field that the reference maps, nested ones included, is
+    the registry entry's, but those that the keys under ``reduced`` set."""
+    from repro import configs
+
+    ref = catalog.reference(config)
+
+    def fields(c):
+        return _flat(ref.program_fields(ref.Sizes.from_config(c)))
+
+    held = fields(config)
+    published = fields(dict(config, **{k: c["published"] for k, c in config["reduced"].items()}))
+    base = configs.get(config["program_arch"])
+    cfg = lm_train.model_config(config)
+    for f, v in held.items():
+        assert _field(base, f) == published[f], f  # the registry's entry is the published model
+        assert _field(cfg, f) == v, f  # the program runs the file's sizes
+    for key, cut in config["reduced"].items():
+        assert config[key] == cut["held"] < cut["published"]
+        assert fields(dict(config, **{key: cut["published"]})) != held, key  # the cut reaches it
+
+
 @pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_keeps_published_widths(c):
     """The file's sizes are the program registry's, but for the cut keys."""
-    from repro import configs
-
     with open(catalog.ROOT / c["file"]) as f:
-        config = json.load(f)
-    cfg = lm_train.model_config(config)
-    base = configs.get(config["program_arch"])
-    for k in ("d_model", "n_heads", "n_kv_heads", "hd", "d_ff", "vocab_size",
-              "tie_embeddings", "rope_theta", "norm_eps"):
-        assert getattr(cfg, k) == getattr(base, k), k
-    for key, cut in config["reduced"].items():
-        assert config[key] == cut["held"] < cut["published"]
-    assert cfg.n_layers == config["num_hidden_layers"]
+        check_published_widths(json.load(f))
 
 
 @pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])

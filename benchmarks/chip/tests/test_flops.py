@@ -64,8 +64,8 @@ def test_forward_flops_match_xla_on_an_unrolled_model():
 
 
 def test_flash_fwd_cost():
-    s = catalog.load_cell("yi6b-train-s4k").sizes
-    f, b = flops.flash_fwd(1, s, 4096)
+    cell = catalog.load_cell("yi6b-train-s4k")
+    f, b = flops.flash_fwd(1, catalog.reference(cell.config).attention_dims(cell.sizes), 4096)
     assert f == 4 * 32 * 128 * 4096 * 4097 / 2
     assert b == 2 * 4096 * 128 * (2 * 32 + 2 * 4)
     peaks = catalog.peaks("TPU v5 lite")

@@ -6,7 +6,7 @@ so every scope reader finds nothing there and returns ``None``.
 """
 
 import json
-import tempfile
+import shutil
 from pathlib import Path
 
 import pytest
@@ -22,10 +22,12 @@ READERS = (flash_bwd_ms_per_step, head_loss_ms_per_step, optimizer_ms_per_step,
 
 def _recording(name: str, chips: int):
     meta = json.loads((DATA / f"{name}.json").read_text())
-    t = trace.load(str(DATA / f"{name}.xplane.pb"), chips)
-    scopes.op_paths(t, [str(DATA / f"{name}.xplane.pb")])
-    return trace.Context(trace=t, cell=None, spans=None, window=meta["window"],
-                         tokens_per_s=1.0, peaks=catalog.peaks("TPU v5 lite"))
+    profile = str(DATA / f"{name}.xplane.pb")
+    ctx = trace.Context(trace=trace.load(profile, chips), cell=None, spans=None,
+                        window=meta["window"], tokens_per_s=1.0,
+                        peaks=catalog.peaks("TPU v5 lite"), profile=profile)
+    scopes.op_paths(ctx)
+    return ctx
 
 
 def test_tf_ops_of_the_device_plane():
@@ -63,33 +65,51 @@ def test_op_paths_give_each_op_its_path(name, chips, share):
                        if o.name.startswith(("%copy-", "%slice-")))
 
 
-def test_op_paths_find_the_profile_under_the_temp_dir(tmp_path, monkeypatch):
+def test_op_paths_find_the_profile_under_the_temp_dir(tmp_path):
     """The runner profiles into a fresh directory under the temporary
-    directory; the file there whose planes hold the trace's ops is taken,
-    and another profile is passed over."""
+    directory and names it in the context; the profile there is read, and a
+    newer one beside it is passed over."""
     name = "v5e_1chip_tiny_step"
     for run, src in (("tmpa", name), ("tmpb", "v5e_4chip_tiny_zero1")):
         d = tmp_path / run / "plugins" / "profile" / "2026_01_01_00_00_00"
         d.mkdir(parents=True)
-        (d / "host.xplane.pb").write_bytes((DATA / f"{src}.xplane.pb").read_bytes())
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
-    assert len(scopes.profiles()) == 2
-    t = trace.load(str(DATA / f"{name}.xplane.pb"), 1)
+        shutil.copy(DATA / f"{src}.xplane.pb", d / "host.xplane.pb")
+    t = trace.load(str(tmp_path / "tmpa"), 1)
+    ctx = trace.Context(trace=t, cell=None, spans=None, window={"steps": 1},
+                        tokens_per_s=1.0, peaks={}, profile=str(tmp_path / "tmpa"))
     (plane,) = t.ops
-    paths = scopes.op_paths(t)
+    paths = scopes.op_paths(ctx)
     assert paths[plane][next(o.name for o in t.ops[plane]
                              if o.name.startswith("%convert_element_type.237 = "))] == (
         "jit(local_step)/jvp()/convert_element_type")
-    assert scopes.op_paths(t) is paths  # read once
+    assert scopes.op_paths(ctx) is paths  # read once
 
 
-def test_op_paths_are_empty_without_the_profile(tmp_path, monkeypatch):
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+def test_op_paths_are_empty_without_the_profile():
     t = trace.load(str(DATA / "v5e_1chip_tiny_step.xplane.pb"), 1)
-    assert scopes.op_paths(t) == {}
     ctx = trace.Context(trace=t, cell=None, spans=None, window={"steps": 1},
                         tokens_per_s=1.0, peaks={})
+    assert scopes.op_paths(ctx) == {}
     assert [r.read(ctx) for r in READERS] == [None] * len(READERS)
+
+
+# device ms a step under a scope of the recordings, as read from a profile
+# found under the temporary directory before the context named it
+SCOPE_MS = [
+    ("v5e_1chip_tiny_step", 1, "checkpoint", "mean", 0.09285317857142858),
+    ("v5e_1chip_tiny_step", 1, "local_step", "max", 0.1779235357142857),
+    ("v5e_4chip_tiny_zero1", 4, "checkpoint", "mean", 0.0921161875),
+    ("v5e_4chip_tiny_zero1", 4, "while", "max", 0.13723491666666668),
+    ("v5e_4chip_tiny_zero1", 4, "local_step", "mean", 0.42090631250000005),
+]
+
+
+@pytest.mark.parametrize("name,chips,scope,over,ms", SCOPE_MS)
+def test_scope_readings_are_unchanged(name, chips, scope, over, ms):
+    ctx = _recording(name, chips)
+    assert scopes.ms_per_step(ctx, lambda path: scopes.under(path, scope), over=over) == ms
+    plane_paths = xspace.tf_ops(ctx.profile, keep=set(ctx.trace.ops).__contains__)
+    assert scopes.op_paths(ctx) == plane_paths
 
 
 def test_segments_take_off_the_transformations():

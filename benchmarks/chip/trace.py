@@ -133,10 +133,14 @@ def short_name(name: str) -> str:
     return head
 
 
-def xplane_file(trace_dir: str) -> str:
-    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+def xplane_file(path: str) -> str:
+    """``path`` itself, or where it is a directory the profiler wrote, the
+    newest ``.xplane.pb`` under it."""
+    if not os.path.isdir(path):
+        return path
+    files = glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)
     if not files:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+        raise FileNotFoundError(f"no .xplane.pb under {path}")
     return max(files, key=os.path.getmtime)
 
 
@@ -145,8 +149,7 @@ def load(path: str, n_devices: int) -> Trace:
     keeping the first ``n_devices`` device planes."""
     from jax.profiler import ProfileData
 
-    if os.path.isdir(path):
-        path = xplane_file(path)
+    path = xplane_file(path)
     pd = ProfileData.from_file(path)
     ops, spans = {}, []
     for plane in pd.planes:
@@ -182,3 +185,4 @@ class Context:
     window: dict
     tokens_per_s: float
     peaks: dict
+    profile: str | None = None  # what ``trace`` was loaded from, for ``scopes.op_paths``

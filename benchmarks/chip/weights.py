@@ -7,9 +7,11 @@ call; the reference makes the same leaves by name.  Neither takes weights from
 the other.
 
 Distributions follow the usual initialisation of a llama-style decoder:
-RMSNorm weights are ones, the embedding is a truncated normal of scale 0.02,
-and every other matrix a truncated normal of scale ``fan_in ** -0.5``, where
-``fan_in`` is the next-to-last dimension.
+RMSNorm gains (``ln1``, ``ln2`` and every leaf whose name ends in ``_norm``,
+such as ``final_norm`` or latent attention's ``kv_norm``) are ones, the
+embedding is a truncated normal of scale 0.02, and every other matrix a
+truncated normal of scale ``fan_in ** -0.5``, where ``fan_in`` is the
+next-to-last dimension.
 """
 
 from __future__ import annotations
@@ -20,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-NORMS = ("ln1", "ln2", "final_norm")
+NORMS = ("ln1", "ln2")
 
 
 def root_key(seed: int):
@@ -34,7 +36,7 @@ def root_key(seed: int):
 def leaf(key, name: str, shape: tuple, dtype):
     """Initial value of the leaf ``name`` (a ``/``-joined path)."""
     last = name.rsplit("/", 1)[-1]
-    if last in NORMS:
+    if last in NORMS or last.endswith("_norm"):
         return jnp.ones(shape, dtype)
     k = jax.random.fold_in(key, np.uint32(zlib.crc32(name.encode())))
     scale = 0.02 if last == "embed" else shape[-2] ** -0.5
